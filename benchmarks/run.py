@@ -100,6 +100,8 @@ def main() -> int:
                     help="bounded sim horizons (fast CI regression check)")
     args = ap.parse_args()
 
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     from benchmarks import (beyond_steal, fig3_aggregation, fig5_prefix,
                             fig6_hitrate, fig8_macro, fig9_pushing,
                             fig10_diurnal, fig11_provision, fig12_fairness,

@@ -29,6 +29,11 @@ twice — through the in-process tick router and through the socket plane
 kill -9s a replica with decode in flight. Gated: `unresolved` == 0 and
 `drill_ok` (the crash loses zero requests). Ungated: the two wall-clock
 tok/s numbers (process parallelism vs socket/codec overhead).
+
+One process per chip: this parent imports JAX before it spawns the plane,
+so on a machine with a chip the parent holds it. The plane children are
+cost-backend and import no JAX; keep them so, because a JAX child could
+not take a chip its parent already holds.
 """
 from __future__ import annotations
 

@@ -25,17 +25,11 @@ import jax.numpy as jnp
 from repro.training.compress import compressed_psum_sum
 
 
-def _axis_size(axis_name) -> int:
-    # jax.lax.axis_size is not available on every jax in the support
-    # window; psum over a constant 1 constant-folds to the (static) size
-    return jax.lax.psum(1, axis_name)
-
-
 def psum_mean(tree: Any, axis_names) -> Any:
     n = 1
     for a in (axis_names if isinstance(axis_names, (tuple, list))
               else (axis_names,)):
-        n *= _axis_size(a)
+        n *= jax.lax.axis_size(a)
     return jax.tree.map(lambda g: jax.lax.psum(g, axis_names) / n, tree)
 
 
@@ -52,7 +46,7 @@ def hierarchical_psum(tree: Any, *, inner_axis: str = "data",
     """Sum over (outer, inner) with minimal traffic on the outer (slow) hop:
     reduce-scatter(inner) -> psum(outer, on 1/inner of the bytes) ->
     all-gather(inner). Exact (no compression)."""
-    inner_n = _axis_size(inner_axis)
+    inner_n = jax.lax.axis_size(inner_axis)
 
     def one(g):
         shape = g.shape
@@ -72,7 +66,7 @@ def compressed_hierarchical_psum(tree: Any, err_state: Any, *,
                                  outer_axis: str = "pod") -> tuple:
     """hierarchical_psum with the cross-pod hop int8-compressed (+ error
     feedback on the shard). Returns (sums, new_err_state)."""
-    inner_n = _axis_size(inner_axis)
+    inner_n = jax.lax.axis_size(inner_axis)
 
     def one(g, e):
         shape = g.shape
@@ -104,7 +98,7 @@ def shard_error_state(params: Any, inner_n: int) -> Any:
 
 def ring_allgather(x: jax.Array, axis_name: str) -> jax.Array:
     """All-gather along axis_name via N-1 ppermute hops (overlappable)."""
-    n = _axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     idx = jax.lax.axis_index(axis_name)
     perm = [(i, (i + 1) % n) for i in range(n)]
     pieces = [x]

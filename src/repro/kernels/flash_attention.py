@@ -18,9 +18,6 @@ from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -2.0e38
 
-# jax < 0.5 spells it TPUCompilerParams
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-
 
 def _kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
             bq: int, bk: int, causal: bool, scale: float, nk: int):
@@ -96,7 +93,7 @@ def flash_attention(q, k, v, *, causal: bool = True, bq: int = 128,
             pltpu.VMEM((bq, 1), jnp.float32),     # running denom l
             pltpu.VMEM((bq, hd), jnp.float32),    # output accumulator
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,

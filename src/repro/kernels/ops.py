@@ -3,7 +3,8 @@
 
 Set REPRO_FORCE_INTERPRET=1 to run the Pallas kernel bodies in interpret
 mode on CPU (used by the kernel test sweeps — validates the kernels
-themselves, not just the oracles).
+themselves, not just the oracles). A backend that fails to start raises
+here; it never turns into a silent oracle run.
 """
 from __future__ import annotations
 
@@ -21,10 +22,7 @@ from repro.kernels.ssd_scan import ssd_scan as _ssd_pallas
 
 
 def _on_tpu() -> bool:
-    try:
-        return jax.default_backend() == "tpu"
-    except RuntimeError:
-        return False
+    return jax.default_backend() == "tpu"
 
 
 def _force_interpret() -> bool:

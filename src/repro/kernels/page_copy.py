@@ -22,9 +22,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax < 0.5 spells it TPUCompilerParams
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-
 
 def _copy_kernel(ids_ref, src_ref, dst_ref):
     dst_ref[...] = src_ref[...]
@@ -53,7 +50,7 @@ def _gather_one(pool, ids, *, interpret: bool):
         _copy_kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((N, L, page, K, hd), pool.dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary")),
         interpret=interpret,
     )(ids, pool)
@@ -87,7 +84,7 @@ def _scatter_one(pool, staged, ids, *, interpret: bool):
         _scatter_kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(pool.shape, pool.dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary")),
         input_output_aliases={2: 0},
         interpret=interpret,

@@ -29,9 +29,6 @@ from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -2.0e38
 
-# jax < 0.5 spells it TPUCompilerParams
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-
 
 def _last_page(seq_len, page: int):
     """Index of the last live page for a sequence (seq_len >= 1)."""
@@ -123,7 +120,7 @@ def paged_decode(q, k_pages, v_pages, block_table, seq_lens, *,
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, H, hd), q.dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(block_table, seq_lens, q, k_pages, v_pages)

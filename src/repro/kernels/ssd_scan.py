@@ -22,9 +22,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax < 0.5 spells it TPUCompilerParams
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-
 
 def _kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, y_ref, h_ref, *,
             chunk: int):
@@ -96,7 +93,7 @@ def ssd_scan(x, dt, a, B_, C_, *, chunk: int = 128,
         out_specs=pl.BlockSpec((1, 1, chunk, P), lambda b, h, c: (b, h, c, 0)),
         out_shape=jax.ShapeDtypeStruct((Bb, H, S, P), x.dtype),
         scratch_shapes=[pltpu.VMEM((P, N), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(x, dt, a, B_, C_)

@@ -1,6 +1,7 @@
-"""Serving launcher: run the paged continuous-batching engine on a reduced
-model with batched requests — single replica, or the full two-layer SkyLB
-router over several in-process replicas across simulated regions. Both
+"""Serving launcher: run the paged continuous-batching engine (bf16
+params, Qwen3's published dtype) with batched requests — single replica,
+or the full two-layer SkyLB router over several in-process replicas across
+simulated regions. Both
 modes drive the UNIFIED front API (`repro.frontend.Client`): submit returns
 a streaming `RequestHandle`, and the reported TTFT comes from each
 request's FIRST TokenEvent, not from the terminal result.
@@ -74,7 +75,7 @@ def serve_single(arch: str, n_requests: int, max_new: int) -> dict:
     from repro.serving import Engine, EngineConfig
 
     cfg = get_config(arch)
-    model = build_model(cfg, jnp.float32)
+    model = build_model(cfg, jnp.bfloat16)
     params = model.init(jax.random.PRNGKey(0))
     eng = Engine(cfg, params, EngineConfig(page_size=8, n_pages=256,
                                            max_batch=8, max_seq_len=1024,
@@ -100,7 +101,7 @@ def serve_multiregion(arch: str, n_requests: int, max_new: int,
     from repro.serving import Engine, EngineConfig, InProcessRouter
 
     cfg = get_config(arch)
-    model = build_model(cfg, jnp.float32)
+    model = build_model(cfg, jnp.bfloat16)
     params = model.init(jax.random.PRNGKey(0))
     # the same build_routing() spec the simulator's ServingSystem uses
     router = InProcessRouter.from_spec(build_routing(variant))
@@ -168,6 +169,9 @@ def main():
     ap.add_argument("--variant", default="skylb",
                     help="routing variant (see repro.routing.VARIANTS)")
     args = ap.parse_args()
+    if not args.procs:                  # --procs never imports JAX
+        from repro.launch.compile_cache import enable_compile_cache
+        enable_compile_cache()
     if args.procs:
         out = serve_procs(args.requests, args.max_new,
                           variant=args.variant.lower(),

@@ -24,12 +24,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-# jax.shard_map only exists on newer jax; fall back to the experimental home
-try:
-    _shard_map = jax.shard_map
-except AttributeError:
-    from jax.experimental.shard_map import shard_map as _shard_map
-
 import os
 
 from repro.configs.base import ModelConfig
@@ -117,7 +111,7 @@ def apply_moe_ep(p: dict, x: jax.Array, cfg: ModelConfig):
         out = jnp.zeros((T_loc, d), ye.dtype).at[slot_tok].add(yw)
         return out.reshape(gl, tl, d), aux
 
-    y, aux = _shard_map(
+    y, aux = jax.shard_map(
         body, mesh=mesh,
         in_specs=(P("data", "model", None), P(None, None),
                   P("model", None, None), P("model", None, None),

@@ -88,6 +88,9 @@ class ServingPlane:
             raise RuntimeError(f"{name} never reported its address")
         tag, addr = parent.recv()
         parent.close()
+        if tag == "error":
+            p.join(5.0)
+            raise RuntimeError(f"{name} failed at start: {addr}")
         assert tag == "addr"
         self.procs[name] = p
         return tuple(addr)

@@ -24,6 +24,9 @@ Two backends share the loop:
     sockets, real PIDs, real kill -9 — without importing JAX.
   * ``jax``  — the real paged `repro.serving.Engine` on a reduced model
     (imported lazily inside the child so cost-mode never pays for it).
+    It serves on the CPU only when ``JAX_PLATFORMS`` says ``cpu``; a
+    replica that finds no accelerator otherwise fails at start and says so
+    over its ready pipe instead of serving on the CPU.
 
 kill -9 needs no cooperation from this file: the process dies, its
 heartbeats stop, the LB's `SocketTransport` goes stale on the link, and
@@ -36,6 +39,7 @@ import os
 import signal
 import sys
 import time
+import traceback
 from typing import Any, Optional
 
 from repro.plane import wire
@@ -236,6 +240,12 @@ def _build_engine(spec: ReplicaSpec):
         from repro.configs import get_config
         from repro.models import build_model
         from repro.serving import Engine, EngineConfig
+        platform = jax.devices()[0].platform
+        if (platform == "cpu" and "cpu" not in
+                os.environ.get("JAX_PLATFORMS", "").split(",")):
+            raise RuntimeError(
+                f"jax replica {spec.rid} found no accelerator (JAX fell back "
+                f"to the CPU); set JAX_PLATFORMS=cpu to serve on the CPU")
         cfg = get_config(spec.arch)
         model = build_model(cfg, jnp.float32)
         params = model.init(jax.random.PRNGKey(0))
@@ -481,7 +491,14 @@ def replica_main(spec_dict: dict, ready) -> None:
     request a graceful drain — Ctrl-C on the process group finishes
     in-flight work instead of dropping it; only kill -9 is abrupt."""
     spec = ReplicaSpec(**spec_dict)
-    server = _ReplicaServer(spec)
+    try:
+        server = _ReplicaServer(spec)
+    except Exception as e:                                  # noqa: BLE001
+        # start-up boundary: the launcher learns why instead of timing out
+        traceback.print_exc()
+        ready.send(("error", f"{type(e).__name__}: {e}"))
+        ready.close()
+        sys.exit(1)
 
     def _graceful(_sig, _frm):
         server.draining = True

@@ -30,14 +30,18 @@ Sampling is per-sequence (each row's temperature/top-k ride in device
 arrays) and batch-shape-invariant and run-stable (PRNG keyed on the request's
 sampling seed + token position),
 so bucketing can never change sampled tokens.
+
+Placement follows the params: the KV pools, the batch state, the PRNG key
+and every upload live on the one device that holds `params`, so engines
+whose params sit on different chips serve from those chips.
 """
 from __future__ import annotations
 
+import functools
 import time
 from typing import Any, Optional
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 
 from repro.configs.base import ModelConfig
@@ -51,8 +55,10 @@ def _gather_pages(k_pages, v_pages, ids):
     return ops.page_gather(k_pages, v_pages, ids)
 
 
-@jax.jit
+@functools.partial(jax.jit, donate_argnums=(0, 1))
 def _scatter_pages(k_pages, v_pages, k_stack, v_stack, ids):
+    # donated: the import updates the pools in place instead of
+    # allocating a second pool beside the first
     return ops.page_scatter(k_pages, v_pages, k_stack, v_stack, ids)
 
 
@@ -77,9 +83,15 @@ class JaxPagedBackend:
         self.bucket_shapes = bucket_shapes
         self.packed_prefill = packed_prefill
         self.overlap_loads = overlap_loads
-        kv_dtype = jax.tree.leaves(params)[0].dtype
+        leaf = jax.tree.leaves(params)[0]
+        kv_dtype = leaf.dtype
+        devices = leaf.devices()
+        if len(devices) != 1:
+            raise ValueError(f"JaxPagedBackend serves params held on one "
+                             f"device; these span {len(devices)}")
+        (self.device,) = devices
         self.k_pages, self.v_pages = mr.init_kv_pool(
-            model_cfg, n_pages, page_size, kv_dtype)
+            model_cfg, n_pages, page_size, kv_dtype, device=self.device)
         # speculative decoding: the drafter keeps its OWN pools with the
         # target pool's page geometry (same page ids index both), so the
         # scheduler manages one set of pages for two models
@@ -92,13 +104,13 @@ class JaxPagedBackend:
                 raise ValueError("spec_k > 0 requires draft_cfg + "
                                  "draft_params (the drafter model)")
             self.dk_pages, self.dv_pages = mr.init_kv_pool(
-                draft_cfg, n_pages, page_size, kv_dtype)
+                draft_cfg, n_pages, page_size, kv_dtype, device=self.device)
         else:
             self.dk_pages = self.dv_pages = None
         self.spec_dispatches = 0      # decode_many calls
         self.spec_drafted = 0         # draft positions proposed
         self.spec_accepted = 0        # draft positions accepted
-        self._base_key = jax.random.PRNGKey(seed)
+        self._base_key = self._put(jax.random.PRNGKey(seed))
         self._scratch: Optional[int] = None
         # host KV tier (allocated at bind when the core enables it)
         self._h_k: Optional[np.ndarray] = None
@@ -107,6 +119,11 @@ class JaxPagedBackend:
         self._staging: dict = {}                     # seq -> staged H2D copy
         self.demoted_pages = 0
         self.loaded_pages = 0
+
+    def _put(self, x):
+        """Upload a host value (or a pytree of them, in one call) to the
+        params' device."""
+        return jax.device_put(x, self.device)
 
     def bind(self, core) -> None:
         if not core.reserved:
@@ -156,7 +173,7 @@ class JaxPagedBackend:
         pad = self._pow2_pad(n)
         ids = np.fromiter((d for d, _ in q), np.int32, n)
         ids = np.concatenate([ids, np.zeros(pad - n, np.int32)])
-        ks, vs = _gather_pages(self.k_pages, self.v_pages, jnp.asarray(ids))
+        ks, vs = _gather_pages(self.k_pages, self.v_pages, self._put(ids))
         kh, vh = np.asarray(ks), np.asarray(vs)      # one sync per flush
         for i, (_, hp) in enumerate(q):
             self._h_k[hp] = kh[i]
@@ -173,8 +190,7 @@ class JaxPagedBackend:
         dev_ids = [dp for _, dp in pairs]
         k_stack = np.stack([self._h_k[hp] for hp, _ in pairs])
         v_stack = np.stack([self._h_v[hp] for hp, _ in pairs])
-        k_dev = jax.device_put(k_stack)
-        v_dev = jax.device_put(v_stack)
+        k_dev, v_dev = self._put((k_stack, v_stack))
         if not self.overlap_loads:                   # serialize (benchmarks)
             jax.block_until_ready((k_dev, v_dev))
         self._staging[seq] = (dev_ids, k_dev, v_dev)
@@ -191,7 +207,7 @@ class JaxPagedBackend:
             reps[:n] = np.arange(n)
             k_dev, v_dev = k_dev[reps], v_dev[reps]
         self.k_pages, self.v_pages = _scatter_pages(
-            self.k_pages, self.v_pages, k_dev, v_dev, jnp.asarray(ids))
+            self.k_pages, self.v_pages, k_dev, v_dev, self._put(ids))
         self.loaded_pages += n
 
     def abort_load(self, seq) -> None:
@@ -205,7 +221,7 @@ class JaxPagedBackend:
         n = len(pages)
         pad = self._pow2_pad(n)
         ids = np.asarray(list(pages) + [0] * (pad - n), np.int32)
-        ks, vs = _gather_pages(self.k_pages, self.v_pages, jnp.asarray(ids))
+        ks, vs = _gather_pages(self.k_pages, self.v_pages, self._put(ids))
         return np.asarray(ks)[:n], np.asarray(vs)[:n]
 
     def import_pages(self, pages: list, k_stack, v_stack) -> None:
@@ -219,20 +235,18 @@ class JaxPagedBackend:
             reps[:n] = np.arange(n)
             k_stack, v_stack = k_stack[reps], v_stack[reps]
         self.k_pages, self.v_pages = _scatter_pages(
-            self.k_pages, self.v_pages, jnp.asarray(k_stack),
-            jnp.asarray(v_stack), jnp.asarray(ids))
+            self.k_pages, self.v_pages, *self._put((k_stack, v_stack, ids)))
 
     # ------------------------------------------------------------ prefill
     def _sample_pref(self, logits, seq, pos: int):
         """Sample one prefill boundary token (same per-row RNG as the
         packed/decode paths, so every path draws identical tokens)."""
         sp = seq.req.sampling
-        tok = mr.sample_rows(
-            logits, self._base_key,
-            jnp.asarray([sp.seed], jnp.int32),
-            jnp.asarray([pos], jnp.int32),
-            jnp.asarray([sp.temperature], jnp.float32),
-            jnp.asarray([sp.top_k], jnp.int32))
+        tok = mr.sample_rows(logits, self._base_key, *self._put((
+            np.asarray([sp.seed], np.int32),
+            np.asarray([pos], np.int32),
+            np.asarray([sp.temperature], np.float32),
+            np.asarray([sp.top_k], np.int32))))
         return int(np.asarray(tok)[0])
 
     def prefill(self, seq, start: int, end: int, sample: bool) -> Optional[int]:
@@ -255,19 +269,17 @@ class JaxPagedBackend:
             np.int32)
         past = seq.pages[:start // ps]
         np_past = np.asarray(past if past else [self._scratch], np.int32)
+        toks, np_new = self._put((toks, np_new))
+        rest = self._put((np_past, np.int32(start), np.int32(len(suffix))))
         logits, self.k_pages, self.v_pages = mr.prefill_step(
-            self.params, jnp.asarray(toks), jnp.asarray(np_new),
-            self.k_pages, self.v_pages, jnp.asarray(np_past),
-            jnp.int32(start), jnp.int32(len(suffix)),
+            self.params, toks, np_new, self.k_pages, self.v_pages, *rest,
             cfg=self.cfg, page_size=ps)
         if self.spec_k > 0:
             # mirror the chunk through the drafter so its cache tracks the
             # target's committed positions (same pages, its own pools)
             _, self.dk_pages, self.dv_pages = mr.prefill_step(
-                self.draft_params, jnp.asarray(toks), jnp.asarray(np_new),
-                self.dk_pages, self.dv_pages, jnp.asarray(np_past),
-                jnp.int32(start), jnp.int32(len(suffix)),
-                cfg=self.draft_cfg, page_size=ps)
+                self.draft_params, toks, np_new, self.dk_pages,
+                self.dv_pages, *rest, cfg=self.draft_cfg, page_size=ps)
         if not sample:
             return None
         tok = self._sample_pref(logits, seq, end)
@@ -325,26 +337,19 @@ class JaxPagedBackend:
             topks[j] = sp.top_k
             seeds[j] = sp.seed
             spos[j] = end
+        tok_rows = self._put((toks, segs, poss, dpage, dslot))
+        seg_rows = self._put((past, past_start, past_len, last_idx, temps,
+                              topks, seeds, spos))
         toks_dev, self.k_pages, self.v_pages = mr.prefill_pack_step(
-            self.params, jnp.asarray(toks), jnp.asarray(segs),
-            jnp.asarray(poss), jnp.asarray(dpage), jnp.asarray(dslot),
-            self.k_pages, self.v_pages, jnp.asarray(past),
-            jnp.asarray(past_start), jnp.asarray(past_len),
-            jnp.asarray(last_idx), jnp.asarray(temps), jnp.asarray(topks),
-            jnp.asarray(seeds), jnp.asarray(spos), self._base_key,
-            cfg=self.cfg, page_size=ps)
+            self.params, *tok_rows, self.k_pages, self.v_pages, *seg_rows,
+            self._base_key, cfg=self.cfg, page_size=ps)
         if self.spec_k > 0:
             # drafter mirror of the whole packed round (sampled boundary
             # tokens are the target's business; the drafter only needs its
             # cache to hold every committed position)
             _, self.dk_pages, self.dv_pages = mr.prefill_pack_step(
-                self.draft_params, jnp.asarray(toks), jnp.asarray(segs),
-                jnp.asarray(poss), jnp.asarray(dpage), jnp.asarray(dslot),
-                self.dk_pages, self.dv_pages, jnp.asarray(past),
-                jnp.asarray(past_start), jnp.asarray(past_len),
-                jnp.asarray(last_idx), jnp.asarray(temps),
-                jnp.asarray(topks), jnp.asarray(seeds), jnp.asarray(spos),
-                self._base_key, cfg=self.draft_cfg, page_size=ps)
+                self.draft_params, *tok_rows, self.dk_pages, self.dv_pages,
+                *seg_rows, self._base_key, cfg=self.draft_cfg, page_size=ps)
         tn = np.asarray(toks_dev)                  # one host sync per round
         now = time.monotonic()
         out: list = []
@@ -396,7 +401,7 @@ class JaxPagedBackend:
          self.dk_pages, self.dv_pages) = mr.spec_decode_step(
             self.params, self.draft_params, self._dstate,
             self.k_pages, self.v_pages, self.dk_pages, self.dv_pages,
-            self._base_key, jnp.int32(self._scratch),
+            self._base_key, self._put(np.int32(self._scratch)),
             cfg=self.cfg, dcfg=self.draft_cfg, page_size=self.page_size,
             nb=self._nb, npgb=self._npgb, k_spec=self.spec_k,
             synth_rate=self.spec_synth_rate)
@@ -449,14 +454,14 @@ class JaxPagedBackend:
             self._npgb = bucket(npg_need, self._npg_cap)
         else:
             self._nb, self._npgb = n, npg_need
-        self._dstate = {
-            "bt": jnp.asarray(self._m_bt),
-            "lens": jnp.asarray(self._m_lens),
-            "toks": jnp.asarray(self._m_toks),
-            "temps": jnp.asarray(self._m_temps),
-            "top_ks": jnp.asarray(self._m_topks),
-            "seeds": jnp.asarray(self._m_seeds),
-        }
+        self._dstate = self._put({
+            "bt": self._m_bt,
+            "lens": self._m_lens,
+            "toks": self._m_toks,
+            "temps": self._m_temps,
+            "top_ks": self._m_topks,
+            "seeds": self._m_seeds,
+        })
 
     # ------------------------------------------------------------ shapes
     # (one implementation for every caller: repro.serving.bucketing)

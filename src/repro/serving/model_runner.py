@@ -57,7 +57,8 @@ from repro.models import attention as attn
 from repro.models import moe as moe_mod
 from repro.models.layers import apply_mlp, embed_tokens, lm_logits, rms_norm
 from repro.kernels import ops as kops
-from repro.serving.sampling import fold_key, sample_rows_impl as _sample_rows
+from repro.serving.sampling import (fold_key, kth_largest,
+                                   sample_rows_impl as _sample_rows)
 
 
 def kv_pool_spec(cfg: ModelConfig, n_pages: int, page_size: int,
@@ -68,9 +69,10 @@ def kv_pool_spec(cfg: ModelConfig, n_pages: int, page_size: int,
 
 
 def init_kv_pool(cfg: ModelConfig, n_pages: int, page_size: int,
-                 dtype=jnp.bfloat16):
+                 dtype=jnp.bfloat16, device=None):
     ks, vs = kv_pool_spec(cfg, n_pages, page_size, dtype)
-    return jnp.zeros(ks.shape, ks.dtype), jnp.zeros(vs.shape, vs.dtype)
+    return (jnp.zeros(ks.shape, ks.dtype, device=device),
+            jnp.zeros(vs.shape, vs.dtype, device=device))
 
 
 def _ffn(lp, h, cfg: ModelConfig):
@@ -112,9 +114,8 @@ def sample(logits: jax.Array, key: jax.Array, *, temperature=0.0,
     greedy = jnp.argmax(lg, axis=-1).astype(jnp.int32)
 
     def topk_mask():
-        srt = jnp.sort(lg, axis=-1)[:, ::-1]
-        kth = jax.lax.dynamic_slice_in_dim(srt, jnp.clip(k, 1, V) - 1, 1,
-                                           axis=-1)
+        ks = jnp.full(lg.shape[:1], jnp.clip(k, 1, V), jnp.int32)
+        kth = kth_largest(lg, ks)[:, None]
         return jnp.where((k > 0) & (lg < kth), -jnp.inf, lg)
 
     def stochastic():

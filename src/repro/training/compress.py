@@ -57,9 +57,7 @@ def compressed_psum(grads: Any, err_state: Any, axis_names) -> tuple:
     n = 1
     for a in (axis_names if isinstance(axis_names, (tuple, list))
               else (axis_names,)):
-        # jax.lax.axis_size is not available on every jax in the support
-        # window; psum over ones is the portable spelling
-        n = n * jax.lax.psum(1, a)
+        n = n * jax.lax.axis_size(a)
     total, new_err = compressed_psum_sum(grads, err_state, axis_names)
     return jax.tree.map(lambda x: x / n, total), new_err
 

@@ -15,7 +15,7 @@ import jax.numpy as jnp
 def test_compressed_psum_single_device_close():
     """axis size 1: compressed psum == identity up to int8 quantization."""
     from jax.sharding import Mesh
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
     from repro.training.compress import compressed_psum, init_error_state
 
@@ -40,7 +40,7 @@ _SUBPROC = textwrap.dedent("""
     import numpy as np
     import jax, jax.numpy as jnp
     from jax.sharding import Mesh, PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from repro.training.compress import compressed_psum, init_error_state
     from repro.distributed.collectives import (hierarchical_psum,
                                                compressed_hierarchical_psum,

@@ -247,6 +247,19 @@ def test_ops_dispatch_cpu_uses_ref(monkeypatch):
     np.testing.assert_allclose(np.asarray(out), np.asarray(want), atol=1e-6)
 
 
+def test_ops_does_not_hide_a_backend_failure(monkeypatch):
+    """A backend that fails to start must surface, not turn into a quiet
+    oracle run that looks like the device path."""
+    from repro.kernels import ops
+
+    def broken():
+        raise RuntimeError("backend failed to initialize")
+    monkeypatch.setattr(jax, "default_backend", broken)
+    q = _rand((1, 2, 16, 8), jnp.float32)
+    with pytest.raises(RuntimeError, match="failed to initialize"):
+        ops.flash_attention(q, q, q)
+
+
 def test_ops_force_interpret(monkeypatch):
     from repro.kernels import ops
     monkeypatch.setenv("REPRO_FORCE_INTERPRET", "1")

@@ -413,3 +413,28 @@ def test_graceful_shutdown_reaps_everything():
     for name, p in plane.procs.items():
         assert p.exitcode == 0, f"{name} exited {p.exitcode}"
     assert not mp.active_children()
+
+
+# ------------------------------------------------------- start-up failures
+
+def test_replica_that_fails_at_start_reports_over_its_pipe():
+    """A replica that cannot build its engine says why on its ready pipe
+    and exits; the launcher raises with that reason instead of timing
+    out (and reaps the child)."""
+    from repro.plane import PlaneConfig, ServingPlane
+    plane = ServingPlane(PlaneConfig(regions=("us",), replicas=1,
+                                     backend="no-such-backend"))
+    with pytest.raises(RuntimeError, match="failed at start.*"
+                                           "no-such-backend"):
+        plane.start()
+    plane.shutdown()
+
+
+def test_jax_replica_refuses_to_serve_on_the_cpu_unasked(monkeypatch):
+    """Without JAX_PLATFORMS naming the CPU, a JAX replica that finds no
+    accelerator fails at start rather than serving on the CPU."""
+    from repro.plane.replica import ReplicaSpec, _build_engine
+    monkeypatch.setenv("JAX_PLATFORMS", "")
+    with pytest.raises(RuntimeError, match="no accelerator"):
+        _build_engine(ReplicaSpec(rid="us-r0", region="us", backend="jax",
+                                  arch="qwen3-0.6b-reduced"))

@@ -115,6 +115,29 @@ def test_sample_fallback_matches_configs():
     assert (one == np.asarray(greedy)).all()
 
 
+@pytest.mark.parametrize("case", ["normal", "ties", "signs"])
+def test_kth_largest_matches_descending_sort(case):
+    """The top-k threshold is the exact value a descending sort puts at
+    index k-1 — ties, negatives, signed zeros and -inf included — so the
+    mask it feeds is the sort-based mask, token for token."""
+    import jax
+    from repro.serving.sampling import kth_largest
+    rng = np.random.default_rng(7)
+    B, V = 6, 257
+    x = rng.normal(scale=4.0, size=(B, V)).astype(np.float32)
+    if case == "ties":
+        x = np.round(x).astype(np.float32)            # heavy ties
+    elif case == "signs":
+        x[:, :40] = -np.abs(x[:, :40])
+        x[:, 40:50] = -0.0
+        x[:, 50:60] = 0.0
+        x[:, 60:64] = -np.inf
+    ks = np.array([1, 2, 17, 128, V - 1, V], np.int32)
+    got = np.asarray(jax.jit(kth_largest)(x, ks))
+    want = np.sort(x, axis=-1)[:, ::-1][np.arange(B), ks - 1]
+    assert (got == want).all(), (got, want)
+
+
 # ----------------------------------------------------------- compile churn
 
 def test_decode_compile_count_bounded(qwen_reduced, qwen_model_params):
