@@ -4,14 +4,11 @@ engine calls, DESIGN §3).
 
 For each kernel x tile configuration we report the STRUCTURAL metrics the
 dry-run perf loop reasons from: per-step VMEM working set, MXU lane
-alignment, grid size — plus interpret-mode wall time on CPU as a smoke
-signal (NOT a TPU number).
+alignment, grid size. Kernel times come from the chip's trace
+(benchmarks/chip/), never from here.
 """
 from __future__ import annotations
 
-import time
-
-import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -34,53 +31,34 @@ def _vmem_ssd(Q, P, N):
     return (Q * P + Q + 2 * Q * N) * 4 + (P * N) * 4 + (Q * Q) * 4
 
 
-def _timeit(fn, *args, reps: int = 3) -> float:
-    fn(*args)                                # compile
-    t0 = time.perf_counter()
-    for _ in range(reps):
-        jax.block_until_ready(fn(*args))
-    return (time.perf_counter() - t0) / reps * 1e6
-
-
 def run() -> list[dict]:
     rows = []
     rng = np.random.default_rng(0)
 
     # flash attention tiles
-    from repro.kernels.ref import flash_attention_ref
-    B, H, K, S, hd = 1, 4, 2, 512, 64
-    q = jnp.asarray(rng.normal(size=(B, H, S, hd)), jnp.float32)
-    k = jnp.asarray(rng.normal(size=(B, K, S, hd)), jnp.float32)
-    v = jnp.asarray(rng.normal(size=(B, K, S, hd)), jnp.float32)
-    us = _timeit(jax.jit(flash_attention_ref), q, k, v)
+    S = 512
     for bq, bk in ((128, 128), (256, 128), (128, 256), (512, 128)):
         rows.append({
             "kernel": "flash_attention", "tile": f"bq{bq}xbk{bk}",
             "vmem_kb": round(_vmem_flash(bq, bk, 128) / 1024, 1),
             "lane_aligned": bk % 128 == 0 and 128 % 128 == 0,
-            "grid": f"(B,H,{S//min(bq,S)},{S//min(bk,S)})",
-            "ref_us_cpu": round(us, 1)})
+            "grid": f"(B,H,{S//min(bq,S)},{S//min(bk,S)})"})
 
     # paged decode tiles
-    from repro.kernels.ref import paged_decode_ref
     B, H, K, hd, page, Ptot, npg = 8, 16, 8, 128, 16, 64, 16
-    q2 = jnp.asarray(rng.normal(size=(B, H, hd)), jnp.float32)
-    kp = jnp.asarray(rng.normal(size=(Ptot, page, K, hd)), jnp.float32)
-    vp = jnp.asarray(rng.normal(size=(Ptot, page, K, hd)), jnp.float32)
-    bt = jnp.asarray(rng.integers(0, Ptot, size=(B, npg)), jnp.int32)
-    ln = jnp.full((B,), npg * page, jnp.int32)
-    us = _timeit(jax.jit(paged_decode_ref), q2, kp, vp, bt, ln)
     for pg in (16, 32, 64, 128):
         rows.append({
             "kernel": "paged_decode", "tile": f"page{pg}",
             "vmem_kb": round(_vmem_paged(pg, 32, 8, 128) / 1024, 1),
             "lane_aligned": 128 % 128 == 0,
-            "grid": f"(B,{(npg*page)//pg})",
-            "ref_us_cpu": round(us, 1)})
+            "grid": f"(B,{(npg*page)//pg})"})
 
     # paged verify (speculative decoding: Q = k_spec + 1 queries per seq)
     from repro.kernels.paged_verify import paged_verify
     from repro.kernels.ref import paged_verify_ref
+    kp = jnp.asarray(rng.normal(size=(Ptot, page, K, hd)), jnp.float32)
+    vp = jnp.asarray(rng.normal(size=(Ptot, page, K, hd)), jnp.float32)
+    bt = jnp.asarray(rng.integers(0, Ptot, size=(B, npg)), jnp.int32)
     for Q in (2, 4):
         qv = jnp.asarray(rng.normal(size=(B, Q, H, hd)), jnp.float32)
         # ragged lens INCLUDING the Q candidate positions
@@ -89,45 +67,32 @@ def run() -> list[dict]:
         oracle = paged_verify_ref(qv, kp, vp, bt, lnv)
         got = paged_verify(qv, kp, vp, bt, lnv, interpret=True)
         err = float(jnp.max(jnp.abs(got - oracle)))
-        us = _timeit(jax.jit(paged_verify_ref), qv, kp, vp, bt, lnv)
         rows.append({
             "kernel": "paged_verify", "tile": f"q{Q}xpage{page}",
             "vmem_kb": round(_vmem_verify(page, H, Q, K, hd) / 1024, 1),
             "lane_aligned": hd % 128 == 0,
             "grid": f"(B,{npg})",
-            "ref_us_cpu": round(us, 1),
             # CI-gated: interpret-mode Pallas vs jnp oracle agreement
             "verify_ok": 1.0 if err < 2e-5 else 0.0})
 
     # ssd chunks
-    from repro.kernels.ref import ssd_scan_ref
-    import functools
-    Bb, Hh, S2, P, G, N = 2, 8, 512, 64, 1, 128
-    x = jnp.asarray(rng.normal(size=(Bb, Hh, S2, P)), jnp.float32)
-    dt = jnp.asarray(rng.uniform(0.01, 0.1, size=(Bb, Hh, S2)), jnp.float32)
-    a = -jnp.asarray(rng.uniform(0.5, 4, size=(Hh,)), jnp.float32)
-    B_ = jnp.asarray(rng.normal(size=(Bb, G, S2, N)), jnp.float32)
-    C_ = jnp.asarray(rng.normal(size=(Bb, G, S2, N)), jnp.float32)
-    us = _timeit(jax.jit(functools.partial(ssd_scan_ref, chunk=128)),
-                 x, dt, a, B_, C_)
+    S2, P, N = 512, 64, 128
     for Q in (64, 128, 256):
         rows.append({
             "kernel": "ssd_scan", "tile": f"chunk{Q}",
             "vmem_kb": round(_vmem_ssd(Q, P, N) / 1024, 1),
             "lane_aligned": N % 128 == 0,
-            "grid": f"(B,H,{S2//Q})",
-            "ref_us_cpu": round(us, 1)})
+            "grid": f"(B,H,{S2//Q})"})
     return rows
 
 
 def main(smoke: bool = False) -> list[dict]:   # fast either way
     rows = run()
     print(f"[kern] {'kernel':16s} {'tile':>12s} {'vmem_kb':>8s} "
-          f"{'aligned':>8s} {'grid':>14s} {'ref_us':>8s}")
+          f"{'aligned':>8s} {'grid':>14s}")
     for r in rows:
         print(f"[kern] {r['kernel']:16s} {r['tile']:>12s} {r['vmem_kb']:8.1f} "
-              f"{str(r['lane_aligned']):>8s} {r['grid']:>14s} "
-              f"{r['ref_us_cpu']:8.1f}")
+              f"{str(r['lane_aligned']):>8s} {r['grid']:>14s}")
     return rows
 
 

@@ -20,8 +20,7 @@ import time
 
 REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
-# deterministic sim metrics worth tracking across PRs (wall-clock metrics
-# like the kernel sweep's *_us timings are deliberately NOT matched)
+# deterministic sim metrics and counts worth tracking across PRs
 SUMMARY_KEYS = frozenset({
     "tok_s", "req_s", "ttft_p50", "ttft_p90", "e2e_p50", "hit_rate",
     "throughput_tok_s", "skylb_tok_s", "local_tok_s", "gap_pct",
@@ -31,9 +30,7 @@ SUMMARY_KEYS = frozenset({
     "cost_usd_per_day", "slo_attainment", "unresolved",
     "global_vs_per_region_saving",
     # serving hot-path gate: compile-count boundedness + deterministic
-    # step/token counts (scheduling must not drift); wall-clock-derived
-    # values (steps_per_s, tok_s, speedup, meets_1_3x) stay ungated like
-    # the kernel timings
+    # step/token counts (scheduling must not drift)
     "decode_programs", "decode_program_bound", "decode_shapes_exact",
     "bounded_ok", "steps", "tokens",
     # hierarchical-KV gate (fig6 host_tier sweep + kv_transfer sim):

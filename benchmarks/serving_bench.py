@@ -11,24 +11,19 @@ Reported and CI-gated (deterministic, machine-independent):
   steps / tokens        per-phase step and token counts (scheduling and
                         sampled tokens must not drift)
 
-Reported only (wall-clock-derived; deliberately NOT in the BENCH_summary
-gate, like the kernel sweep's *_us timings): steps_per_s, tok_s, speedup,
-and the meets_1_3x indicator. The bucketed engine runs FIRST, so any
-jit-cache sharing between the two phases only ever helps the exact-shape
-baseline — the reported speedup is conservative.
+Nothing here is a time: every number is a count, so a regeneration on any
+machine reproduces it. Speed is measured on the chip (benchmarks/chip/).
 
-The host_tier section measures load-back overlap: a replay of demoted
-prompts through an engine whose host tier is on, once with the H2D page
-staging dispatched concurrently with decode (overlap_loads=True, the
-default) and once forced synchronous. Wall-clock steps/s for both runs are
-reported ungated; host_hits_tok confirms the replay actually load-backs.
+The host_tier section replays demoted prompts through an engine whose host
+tier is on, once with the H2D page staging dispatched concurrently with
+decode (overlap_loads=True, the default) and once forced synchronous;
+host_hits_tok confirms the replay actually loads back.
 
 The multiprocess section runs the SAME cost-model engines and workload
 twice — through the in-process tick router and through the socket plane
 (repro.plane: real processes, real TCP, sender-paced WAN delay) — then
 kill -9s a replica with decode in flight. Gated: `unresolved` == 0 and
-`drill_ok` (the crash loses zero requests). Ungated: the two wall-clock
-tok/s numbers (process parallelism vs socket/codec overhead).
+`drill_ok` (the crash loses zero requests).
 
 One process per chip: this parent imports JAX before it spawns the plane,
 so on a machine with a chip the parent holds it. The plane children are
@@ -59,20 +54,13 @@ def _drive(model_cfg, params, reqs, *, bucketed: bool):
                         bucket_shapes=bucketed, packed_prefill=bucketed)
     eng = Engine(model_cfg, params, ecfg, seed=0)
     before = mr.compile_counts()
-    t0 = time.perf_counter()
     res = eng.generate([GenRequest(
         prompt_tokens=p, sampling=SamplingParams(max_new_tokens=m))
         for p, m in reqs])
-    wall = time.perf_counter() - t0
     after = mr.compile_counts()
-    toks = sum(len(r.output_tokens) for r in res)
-    steps = eng.steps
     return {
-        "wall_s": round(wall, 3),
-        "steps": steps,
-        "tokens": toks,
-        "steps_per_s": round(steps / wall, 2),
-        "tok_s_wall": round(toks / wall, 2),   # _wall: dodge the gated sim key
+        "steps": eng.steps,
+        "tokens": sum(len(r.output_tokens) for r in res),
         "decode_compiles": after["decode_step"] - before["decode_step"],
         "prefill_compiles": (
             after["prefill_pack_step"] - before["prefill_pack_step"]
@@ -102,7 +90,6 @@ def main(smoke: bool = False) -> dict:
 
     bound = (n_buckets(ecfg.max_batch)
              * n_buckets(-(-ecfg.max_seq_len // ecfg.page_size)))
-    speedup = bucketed["steps_per_s"] / max(exact["steps_per_s"], 1e-9)
     out = {
         "smoke": smoke,
         "n_requests": len(reqs),
@@ -111,8 +98,6 @@ def main(smoke: bool = False) -> dict:
         "decode_programs": bucketed["decode_compiles"],
         "decode_program_bound": bound,
         "decode_shapes_exact": exact["decode_compiles"],
-        "speedup": round(speedup, 2),
-        "meets_1_3x": 1.0 if speedup >= 1.3 else 0.0,
         "bounded_ok": 1.0 if bucketed["decode_compiles"] <= bound else 0.0,
         "deadlines": deadlines,
         "host_tier": host_tier,
@@ -122,20 +107,18 @@ def main(smoke: bool = False) -> dict:
     }
     for name, row in (("bucketed", bucketed), ("exact", exact)):
         print(f"[serving] {name:9s} {row['steps']:4d} steps "
-              f"{row['steps_per_s']:8.2f} steps/s {row['tok_s_wall']:8.2f} tok/s "
+              f"{row['tokens']:5d} tokens "
               f"{row['decode_compiles']:3d} decode compiles "
               f"{row['prefill_compiles']:3d} prefill compiles")
-    print(f"[serving] speedup {speedup:.2f}x (gate >= 1.3x: "
-          f"{'OK' if out['meets_1_3x'] else 'FAIL'}); decode programs "
-          f"{out['decode_programs']} <= bound {bound} "
-          f"(exact-shape churn: {out['decode_shapes_exact']})")
+    print(f"[serving] decode programs {out['decode_programs']} <= bound "
+          f"{bound} (exact-shape churn: {out['decode_shapes_exact']})")
     print(f"[serving] deadlines: {deadlines['deadline_aborted_n']} aborted "
           f"(FinishReason.DEADLINE), goodput {deadlines['goodput_tok']} of "
           f"{deadlines['offered_tok']} offered tok "
           f"({100 * deadlines['goodput_frac']:.0f}%)")
-    print(f"[serving] host tier: replay {host_tier['overlap']['replay_steps_per_s']:.2f}"
-          f" steps/s overlapped vs {host_tier['blocking']['replay_steps_per_s']:.2f}"
-          f" blocking ({host_tier['overlap_speedup']:.2f}x), "
+    print(f"[serving] host tier: replay "
+          f"{host_tier['overlap']['replay_steps_n']} steps overlapped, "
+          f"{host_tier['blocking']['replay_steps_n']} blocking, "
           f"{host_tier['overlap']['host_hits_tok']} host-hit tok")
     print(f"[serving] speculation: {speculation['spec_tokens_per_dispatch']:.2f}"
           f" tok/seq/dispatch (gate > 1.5), acceptance "
@@ -147,10 +130,8 @@ def main(smoke: bool = False) -> dict:
           f"{hedging['off_ttft_p99_s']:.3f}s -> {hedging['on_ttft_p99_s']:.3f}s"
           f" ({hedging['hedge_n']} hedged, {hedging['hedge_wins_n']} wins, "
           f"{hedging['hedge_wasted_tok']} wasted tok)")
-    print(f"[serving] multiprocess: {multiprocess['procs_tok_s_wall']:.1f}"
-          f" tok/s over {multiprocess['n_processes']} processes vs "
-          f"{multiprocess['inproc_tok_s_wall']:.1f} in-process "
-          f"({multiprocess['procs_speedup_wall']:.2f}x); kill -9 drill "
+    print(f"[serving] multiprocess: {multiprocess['n_processes']} processes;"
+          f" kill -9 drill "
           f"re-dispatched {multiprocess['drill_redispatched_n']}, "
           f"unresolved {multiprocess['unresolved']} (gate == 0); "
           f"partition drill re-homed {multiprocess['partition_rehomed_n']}, "
@@ -171,8 +152,7 @@ def _multiprocess(smoke: bool) -> dict:
     is blackholed from its peers and the client mid-stream (silence, not
     EOF), the client re-homes its parked requests, and after the heal the
     zombie region's frames are fenced so every request resolves exactly
-    once. Ungated (wall-clock, machine-local): the two tok/s numbers —
-    real process parallelism vs socket/codec overhead."""
+    once."""
     from repro.frontend import Client, RequestState, RouterHost
     from repro.plane import CostEngine, PlaneConfig, ServingPlane, blackhole
     from repro.routing import build_routing
@@ -200,13 +180,10 @@ def _multiprocess(smoke: bool) -> dict:
         for k in range(2):
             lb.add_engine(f"{region}-r{k}", CostEngine(time_scale=tscale))
     client = Client(RouterHost(router))
-    t0 = time.perf_counter()
     handles = [client.submit(r, region=skew(i))
                for i, r in enumerate(reqs())]
     client.drain()
-    inproc_wall = time.perf_counter() - t0
     assert all(h.state is RequestState.FINISHED for h in handles)
-    toks = sum(len(h.result.output_tokens) for h in handles)
 
     # the socket plane: one OS process per engine and per LB
     plane = ServingPlane(PlaneConfig(
@@ -215,13 +192,10 @@ def _multiprocess(smoke: bool) -> dict:
     host = plane.host()
     try:
         pclient = Client(host)
-        t0 = time.perf_counter()
         ph = [pclient.submit(r, region=skew(i))
               for i, r in enumerate(reqs())]
         pclient.drain()
-        procs_wall = time.perf_counter() - t0
         assert all(h.state is RequestState.FINISHED for h in ph)
-        ptoks = sum(len(h.result.output_tokens) for h in ph)
 
         # partition-and-heal drill: blackhole eu's LB from its peer and
         # the client mid-stream (silence, not EOF — TCP stays up), let the
@@ -285,24 +259,19 @@ def _multiprocess(smoke: bool) -> dict:
         "duplicate_results": pc["duplicate_results"],
         "partition_fenced_n": pc["fenced_frames"],
         "partition_rehomed_n": rehomed_n,
-        # ungated detail + wall-clock (names dodge the gated key set)
+        # ungated detail
         "n_requests": n,
         "n_processes": m["n_processes"],
         "drill_redispatched_n": m["redispatched"],
-        "inproc_tok_s_wall": round(toks / inproc_wall, 1),
-        "procs_tok_s_wall": round(ptoks / procs_wall, 1),
-        "procs_speedup_wall": round((ptoks / procs_wall)
-                                    / max(toks / inproc_wall, 1e-9), 2),
     }
 
 
 def _host_tier_overlap(model_cfg, params) -> dict:
-    """Load-back overlap, wall-clock (ungated): the same eviction-pressure
-    replay — six prompts sharing a 40-token stem through a device pool that
-    holds barely two of them, then replayed so the demoted chains load back
-    from the host pool — with the double-buffered H2D staging dispatched
-    concurrently with decode vs forced synchronous. Key names avoid the
-    CI-gated set (steps/tokens/...): wall-clock numbers are machine-local."""
+    """Load-back under eviction pressure (ungated counts): six prompts
+    sharing a 40-token stem through a device pool that holds barely two of
+    them, then replayed so the demoted chains load back from the host pool
+    — with the double-buffered H2D staging dispatched concurrently with
+    decode vs forced synchronous."""
     import dataclasses as _dc
     from repro.serving import Engine, EngineConfig, GenRequest, SamplingParams
 
@@ -322,32 +291,19 @@ def _host_tier_overlap(model_cfg, params) -> dict:
     def drive(overlap: bool) -> dict:
         eng = Engine(model_cfg, params,
                      _dc.replace(ecfg, overlap_loads=overlap), seed=0)
-        eng.generate(reqs())            # warm + demote under pressure
+        eng.generate(reqs())            # demote under pressure
         s0, h0 = eng.steps, eng.core.host_hit_tokens
-        t0 = time.perf_counter()
-        res = eng.generate(reqs())      # replay: host hits -> load-backs
-        wall = time.perf_counter() - t0
-        toks = sum(len(r.output_tokens) for r in res)
+        eng.generate(reqs())            # replay: host hits -> load-backs
         return {
-            "replay_wall_s": round(wall, 3),
             "replay_steps_n": eng.steps - s0,
-            "replay_steps_per_s": round((eng.steps - s0) / wall, 2),
-            "replay_tok_s": round(toks / wall, 2),
             "host_hits_tok": eng.core.host_hit_tokens - h0,
             "loaded_pages": eng.backend.loaded_pages,
         }
 
-    drive(True)                 # untimed: pays the shared jit compiles
     overlap = drive(True)
     blocking = drive(False)
     assert overlap["host_hits_tok"] > 0, "replay produced no load-backs"
-    return {
-        "overlap": overlap,
-        "blocking": blocking,
-        "overlap_speedup": round(overlap["replay_steps_per_s"]
-                                 / max(blocking["replay_steps_per_s"], 1e-9),
-                                 2),
-    }
+    return {"overlap": overlap, "blocking": blocking}
 
 
 def _speculation(model_cfg, params, reqs, ecfg) -> dict:

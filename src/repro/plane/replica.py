@@ -151,10 +151,11 @@ class CostEngine:
         return True
 
     def step(self) -> int:
+        t_admit = time.monotonic()      # before the admissions' prefill
         plan = self.core.begin_step()
         for seq in plan.admitted:
             if seq.req.on_admit is not None:
-                seq.req.on_admit(seq.req, time.monotonic())
+                seq.req.on_admit(seq.req, t_admit)
         for seq in plan.rejected:
             self._finish(seq, FinishReason.ABORT)
         # plan.shed stays empty on the socket plane (deliver frames strip

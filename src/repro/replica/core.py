@@ -210,9 +210,7 @@ class ReplicaCore:
         self.rejections = 0
         self.preemptions = 0
         self.cancellations = 0
-        self.peak_running = 0
         self.peak_outstanding = 0
-        self.peak_pages = 0
         self._admit_counter = 0
         # (head seq, radix content version, free pages) of the last
         # capacity-blocked admission attempt: while none of the three
@@ -520,9 +518,7 @@ class ReplicaCore:
                 self._record("admit_fair", seq.req.rid, tenant_of(seq.req))
         self._flush_prefills()
         self.steps += 1
-        self.peak_running = max(self.peak_running, len(self.running))
         self.peak_outstanding = max(self.peak_outstanding, self.outstanding())
-        self.peak_pages = max(self.peak_pages, self.alloc.used_pages)
         return StepPlan(admitted, rejected, shed)
 
     def _finish_loads(self, admitted: list) -> None:

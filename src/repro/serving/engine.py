@@ -26,6 +26,7 @@ from repro.replica import ReplicaCore, ReplicaCoreConfig
 from repro.serving.jax_backend import JaxPagedBackend
 from repro.serving.request import (FinishReason, GenRequest, GenResult,
                                    cancel_finish_reason)
+from repro.serving.spans import install_gc_span, span
 
 
 @dataclasses.dataclass(frozen=True)
@@ -96,6 +97,7 @@ class Engine:
         self._tokbuf: list = []
         self.core.token_sink = (
             lambda seq, tok, idx: self._tokbuf.append((seq, tok, idx)))
+        install_gc_span()
 
     # ------------------------------------------------------------ probes
     def pending_count(self) -> int:
@@ -155,19 +157,17 @@ class Engine:
     def completions(self) -> int:
         return self.core.completions
 
-    @property
-    def peak_running(self) -> int:
-        return self.core.peak_running
-
     def tenant_counters(self) -> dict:
         return self.core.tenant_counters()
 
     # ------------------------------------------------------------ submit
     def submit(self, req: GenRequest) -> None:
+        now = time.monotonic()
         if req.arrival_s is None:
             # admission stamp from THIS transport's clock — never the
             # dataclass-construction time
-            req.arrival_s = time.monotonic()
+            req.arrival_s = now
+        req.dispatched_s = now
         if req.cancelled is not None:
             # a cancel raced the request here over the router's WAN:
             # resolve it at arrival, exactly once
@@ -213,22 +213,32 @@ class Engine:
         batch. Returns #sequences terminally resolved this step (finished +
         rejected + deadline-aborted) — every one has a GenResult in
         `results`. Token events (`req.on_token`) drain once per step."""
-        aborted = self._sweep_deadlines(time.monotonic())
-        plan = self.core.begin_step()
-        for seq in plan.admitted:
-            if seq.req.on_admit is not None:
-                seq.req.on_admit(seq.req, time.monotonic())
-        for seq in plan.rejected:
-            self._finish(seq, FinishReason.ABORT)
-        for seq in plan.shed:
-            self._finish(seq, FinishReason.SHED)
-        finished = self.core.finish_step()
-        self._drain_tokens()
-        for seq in finished:
-            why = (FinishReason.LENGTH if len(seq.out) >= seq.max_new
-                   else FinishReason.STOP)
-            self._finish(seq, why)
-        return len(finished) + len(plan.rejected) + len(plan.shed) + aborted
+        with span("engine.step"):
+            aborted = self._sweep_deadlines(time.monotonic())
+            # the admission time is taken before begin_step, which runs
+            # the admissions' prefill
+            t_admit = time.monotonic()
+            with span("replica.admit"):
+                plan = self.core.begin_step()
+            for seq in plan.admitted:
+                if seq.req.admitted_s is None:
+                    seq.req.admitted_s = t_admit
+                if seq.req.on_admit is not None:
+                    seq.req.on_admit(seq.req, t_admit)
+            for seq in plan.rejected:
+                self._finish(seq, FinishReason.ABORT)
+            for seq in plan.shed:
+                self._finish(seq, FinishReason.SHED)
+            with span("replica.finish"):
+                finished = self.core.finish_step()
+            with span("engine.stream"):
+                self._drain_tokens()
+                for seq in finished:
+                    why = (FinishReason.LENGTH if len(seq.out) >= seq.max_new
+                           else FinishReason.STOP)
+                    self._finish(seq, why)
+            return (len(finished) + len(plan.rejected) + len(plan.shed)
+                    + aborted)
 
     def _drain_tokens(self) -> None:
         if not self._tokbuf:

@@ -48,6 +48,7 @@ from repro.configs.base import ModelConfig
 from repro.kernels import ops
 from repro.serving import model_runner as mr
 from repro.serving.bucketing import bucket, pow2_pad, token_pad
+from repro.serving.spans import span
 
 
 @jax.jit
@@ -119,6 +120,8 @@ class JaxPagedBackend:
         self._staging: dict = {}                     # seq -> staged H2D copy
         self.demoted_pages = 0
         self.loaded_pages = 0
+        self.decode_calls = 0         # decode / decode_many dispatches
+        self.state_uploads = 0        # _sync_slots calls (membership changed)
 
     def _put(self, x):
         """Upload a host value (or a pytree of them, in one call) to the
@@ -247,7 +250,8 @@ class JaxPagedBackend:
             np.asarray([pos], np.int32),
             np.asarray([sp.temperature], np.float32),
             np.asarray([sp.top_k], np.int32))))
-        return int(np.asarray(tok)[0])
+        with span("backend.sync"):
+            return int(np.asarray(tok)[0])
 
     def prefill(self, seq, start: int, end: int, sample: bool) -> Optional[int]:
         """One-request fallback (`packed_prefill=False`); the packed path
@@ -290,96 +294,103 @@ class JaxPagedBackend:
     def prefill_batch(self, items) -> list:
         """Packed batched prefill: one dispatch for a whole admission round.
         items: [(seq, start, end, sample)] with page-aligned starts."""
-        if not self.packed_prefill:
-            return [self.prefill(seq, s, e, smp) for seq, s, e, smp in items]
-        self._flush_demotes()
-        ps = self.page_size
-        nseg = len(items)
-        seg_lens = [end - start for _, start, end, _ in items]
-        S = self._token_pad(sum(seg_lens))
-        toks = np.zeros(S, np.int32)
-        segs = np.full(S, -1, np.int32)
-        poss = np.zeros(S, np.int32)
-        dpage = np.full(S, self._scratch, np.int32)
-        dslot = np.zeros(S, np.int32)
-        past_lists = []
-        off = 0
-        for j, (seq, start, end, _) in enumerate(items):
-            n = end - start
-            idx = np.arange(start, end)
-            toks[off:off + n] = seq.tokens[start:end]
-            segs[off:off + n] = j
-            poss[off:off + n] = idx
-            dpage[off:off + n] = np.asarray(seq.pages, np.int32)[idx // ps]
-            dslot[off:off + n] = idx % ps
-            past_lists.append(seq.pages[:start // ps])
-            off += n
-        cp_off = np.cumsum([0] + [len(p) for p in past_lists])
-        CP = self._pow2_pad(max(int(cp_off[-1]), 1))
-        past = np.full(CP, self._scratch, np.int32)
-        for j, pages in enumerate(past_lists):
-            past[cp_off[j]:cp_off[j + 1]] = pages
-        NS = self._pow2_pad(nseg)
-        past_start = np.zeros(NS, np.int32)
-        past_len = np.zeros(NS, np.int32)
-        last_idx = np.zeros(NS, np.int32)
-        temps = np.zeros(NS, np.float32)
-        topks = np.zeros(NS, np.int32)
-        seeds = np.zeros(NS, np.int32)
-        spos = np.zeros(NS, np.int32)
-        seg_off = np.cumsum([0] + seg_lens)
-        for j, (seq, start, end, _) in enumerate(items):
-            sp = seq.req.sampling
-            past_start[j] = cp_off[j] * ps
-            past_len[j] = start
-            last_idx[j] = seg_off[j + 1] - 1
-            temps[j] = sp.temperature
-            topks[j] = sp.top_k
-            seeds[j] = sp.seed
-            spos[j] = end
-        tok_rows = self._put((toks, segs, poss, dpage, dslot))
-        seg_rows = self._put((past, past_start, past_len, last_idx, temps,
-                              topks, seeds, spos))
-        toks_dev, self.k_pages, self.v_pages = mr.prefill_pack_step(
-            self.params, *tok_rows, self.k_pages, self.v_pages, *seg_rows,
-            self._base_key, cfg=self.cfg, page_size=ps)
-        if self.spec_k > 0:
-            # drafter mirror of the whole packed round (sampled boundary
-            # tokens are the target's business; the drafter only needs its
-            # cache to hold every committed position)
-            _, self.dk_pages, self.dv_pages = mr.prefill_pack_step(
-                self.draft_params, *tok_rows, self.dk_pages, self.dv_pages,
-                *seg_rows, self._base_key, cfg=self.draft_cfg, page_size=ps)
-        tn = np.asarray(toks_dev)                  # one host sync per round
-        now = time.monotonic()
-        out: list = []
-        for j, (seq, _start, _end, smp) in enumerate(items):
-            if not smp:
-                out.append(None)
-                continue
-            if seq.req.first_token_s is None:
-                seq.req.first_token_s = now
-            out.append(int(tn[j]))
-        return out
+        with span("backend.prefill"):
+            if not self.packed_prefill:
+                return [self.prefill(seq, s, e, smp)
+                        for seq, s, e, smp in items]
+            self._flush_demotes()
+            ps = self.page_size
+            nseg = len(items)
+            seg_lens = [end - start for _, start, end, _ in items]
+            S = self._token_pad(sum(seg_lens))
+            toks = np.zeros(S, np.int32)
+            segs = np.full(S, -1, np.int32)
+            poss = np.zeros(S, np.int32)
+            dpage = np.full(S, self._scratch, np.int32)
+            dslot = np.zeros(S, np.int32)
+            past_lists = []
+            off = 0
+            for j, (seq, start, end, _) in enumerate(items):
+                n = end - start
+                idx = np.arange(start, end)
+                toks[off:off + n] = seq.tokens[start:end]
+                segs[off:off + n] = j
+                poss[off:off + n] = idx
+                dpage[off:off + n] = np.asarray(seq.pages, np.int32)[idx // ps]
+                dslot[off:off + n] = idx % ps
+                past_lists.append(seq.pages[:start // ps])
+                off += n
+            cp_off = np.cumsum([0] + [len(p) for p in past_lists])
+            CP = self._pow2_pad(max(int(cp_off[-1]), 1))
+            past = np.full(CP, self._scratch, np.int32)
+            for j, pages in enumerate(past_lists):
+                past[cp_off[j]:cp_off[j + 1]] = pages
+            NS = self._pow2_pad(nseg)
+            past_start = np.zeros(NS, np.int32)
+            past_len = np.zeros(NS, np.int32)
+            last_idx = np.zeros(NS, np.int32)
+            temps = np.zeros(NS, np.float32)
+            topks = np.zeros(NS, np.int32)
+            seeds = np.zeros(NS, np.int32)
+            spos = np.zeros(NS, np.int32)
+            seg_off = np.cumsum([0] + seg_lens)
+            for j, (seq, start, end, _) in enumerate(items):
+                sp = seq.req.sampling
+                past_start[j] = cp_off[j] * ps
+                past_len[j] = start
+                last_idx[j] = seg_off[j + 1] - 1
+                temps[j] = sp.temperature
+                topks[j] = sp.top_k
+                seeds[j] = sp.seed
+                spos[j] = end
+            tok_rows = self._put((toks, segs, poss, dpage, dslot))
+            seg_rows = self._put((past, past_start, past_len, last_idx, temps,
+                                  topks, seeds, spos))
+            toks_dev, self.k_pages, self.v_pages = mr.prefill_pack_step(
+                self.params, *tok_rows, self.k_pages, self.v_pages, *seg_rows,
+                self._base_key, cfg=self.cfg, page_size=ps)
+            if self.spec_k > 0:
+                # drafter mirror of the whole packed round (sampled boundary
+                # tokens are the target's business; the drafter only needs its
+                # cache to hold every committed position)
+                _, self.dk_pages, self.dv_pages = mr.prefill_pack_step(
+                    self.draft_params, *tok_rows, self.dk_pages, self.dv_pages,
+                    *seg_rows, self._base_key, cfg=self.draft_cfg,
+                    page_size=ps)
+            with span("backend.sync"):
+                tn = np.asarray(toks_dev)          # one host sync per round
+            now = time.monotonic()
+            out: list = []
+            for j, (seq, _start, _end, smp) in enumerate(items):
+                if not smp:
+                    out.append(None)
+                    continue
+                if seq.req.first_token_s is None:
+                    seq.req.first_token_s = now
+                out.append(int(tn[j]))
+            return out
 
     # ------------------------------------------------------------ decode
     def decode(self, seqs) -> list[int]:
-        self._flush_demotes()
-        n = len(seqs)
-        if not self._slots_current(seqs):
-            self._sync_slots(seqs)
-        toks, self._dstate, self.k_pages, self.v_pages = mr.decode_step(
-            self.params, self._dstate, self.k_pages, self.v_pages,
-            self._base_key, cfg=self.cfg, page_size=self.page_size,
-            nb=self._nb, npgb=self._npgb)
-        out = np.asarray(toks)                 # the single host sync
-        # advance the mirrors exactly like the fused step advanced the
-        # device state (active rows only)
-        active = self._m_lens[:self._nb] > 0
-        self._m_lens[:self._nb] += active
-        self._m_toks[:self._nb] = np.where(active, out[:self._nb],
-                                           self._m_toks[:self._nb])
-        return [int(t) for t in out[:n]]
+        with span("backend.decode"):
+            self._flush_demotes()
+            n = len(seqs)
+            self.decode_calls += 1
+            if not self._slots_current(seqs):
+                self._sync_slots(seqs)
+            toks, self._dstate, self.k_pages, self.v_pages = mr.decode_step(
+                self.params, self._dstate, self.k_pages, self.v_pages,
+                self._base_key, cfg=self.cfg, page_size=self.page_size,
+                nb=self._nb, npgb=self._npgb)
+            with span("backend.sync"):
+                out = np.asarray(toks)             # the single host sync
+            # advance the mirrors exactly like the fused step advanced the
+            # device state (active rows only)
+            active = self._m_lens[:self._nb] > 0
+            self._m_lens[:self._nb] += active
+            self._m_toks[:self._nb] = np.where(active, out[:self._nb],
+                                               self._m_toks[:self._nb])
+            return [int(t) for t in out[:n]]
 
     def decode_many(self, seqs) -> Optional[list]:
         """ReplicaCore's speculative step contract: None when speculation
@@ -393,30 +404,34 @@ class JaxPagedBackend:
         prefix degrades acceptance, never correctness."""
         if self.spec_k <= 0:
             return None
-        self._flush_demotes()
-        n = len(seqs)
-        if not self._slots_current(seqs):
-            self._sync_slots(seqs)
-        (T, n_acc, self._dstate, self.k_pages, self.v_pages,
-         self.dk_pages, self.dv_pages) = mr.spec_decode_step(
-            self.params, self.draft_params, self._dstate,
-            self.k_pages, self.v_pages, self.dk_pages, self.dv_pages,
-            self._base_key, self._put(np.int32(self._scratch)),
-            cfg=self.cfg, dcfg=self.draft_cfg, page_size=self.page_size,
-            nb=self._nb, npgb=self._npgb, k_spec=self.spec_k,
-            synth_rate=self.spec_synth_rate)
-        Tn, an = jax.device_get((T, n_acc))        # the single host sync
-        # advance the mirrors exactly like the fused step advanced the
-        # device state (active rows move past their accepted run + 1)
-        active = self._m_lens[:self._nb] > 0
-        self._m_lens[:self._nb] += np.where(active, an + 1, 0).astype(np.int32)
-        rows = np.arange(self._nb)
-        self._m_toks[:self._nb] = np.where(active, Tn[rows, an],
-                                           self._m_toks[:self._nb])
-        self.spec_dispatches += 1
-        self.spec_drafted += n * self.spec_k
-        self.spec_accepted += int(an[:n].sum())
-        return [[int(t) for t in Tn[i, :an[i] + 1]] for i in range(n)]
+        with span("backend.decode"):
+            self._flush_demotes()
+            n = len(seqs)
+            self.decode_calls += 1
+            if not self._slots_current(seqs):
+                self._sync_slots(seqs)
+            (T, n_acc, self._dstate, self.k_pages, self.v_pages,
+             self.dk_pages, self.dv_pages) = mr.spec_decode_step(
+                self.params, self.draft_params, self._dstate,
+                self.k_pages, self.v_pages, self.dk_pages, self.dv_pages,
+                self._base_key, self._put(np.int32(self._scratch)),
+                cfg=self.cfg, dcfg=self.draft_cfg, page_size=self.page_size,
+                nb=self._nb, npgb=self._npgb, k_spec=self.spec_k,
+                synth_rate=self.spec_synth_rate)
+            with span("backend.sync"):
+                Tn, an = jax.device_get((T, n_acc))    # the single host sync
+            # advance the mirrors exactly like the fused step advanced the
+            # device state (active rows move past their accepted run + 1)
+            active = self._m_lens[:self._nb] > 0
+            self._m_lens[:self._nb] += np.where(active, an + 1,
+                                                0).astype(np.int32)
+            rows = np.arange(self._nb)
+            self._m_toks[:self._nb] = np.where(active, Tn[rows, an],
+                                               self._m_toks[:self._nb])
+            self.spec_dispatches += 1
+            self.spec_drafted += n * self.spec_k
+            self.spec_accepted += int(an[:n].sum())
+            return [[int(t) for t in Tn[i, :an[i] + 1]] for i in range(n)]
 
     def _slots_current(self, seqs) -> bool:
         if len(self._slots) != len(seqs):
@@ -427,41 +442,43 @@ class JaxPagedBackend:
     def _sync_slots(self, seqs) -> None:
         """Batch membership changed: rewrite the rows that differ, zero the
         rows that emptied, pick the shape bucket, upload the state."""
-        n = len(seqs)
-        old = self._slots
-        for i, s in enumerate(seqs):
-            if i < len(old) and old[i][0] is s and old[i][1] is s.pages:
-                continue
-            self._m_bt[i, :] = self._scratch
-            self._m_bt[i, :len(s.pages)] = s.pages
-            self._m_lens[i] = s.pos - 1        # last token not yet in cache
-            self._m_toks[i] = s.tokens[-1]
-            sp = s.req.sampling
-            self._m_temps[i] = sp.temperature
-            self._m_topks[i] = sp.top_k
-            self._m_seeds[i] = sp.seed
-        for i in range(n, len(old)):           # rows that shrank away
-            self._m_bt[i, :] = self._scratch
-            self._m_lens[i] = 0
-            self._m_toks[i] = 0
-            self._m_temps[i] = 0.0
-            self._m_topks[i] = 0
-            self._m_seeds[i] = 0
-        self._slots = [(s, s.pages) for s in seqs]
-        npg_need = max(len(s.pages) for s in seqs)
-        if self.bucket_shapes:
-            self._nb = bucket(n, self._bcap)
-            self._npgb = bucket(npg_need, self._npg_cap)
-        else:
-            self._nb, self._npgb = n, npg_need
-        self._dstate = self._put({
-            "bt": self._m_bt,
-            "lens": self._m_lens,
-            "toks": self._m_toks,
-            "temps": self._m_temps,
-            "top_ks": self._m_topks,
-            "seeds": self._m_seeds,
-        })
+        self.state_uploads += 1
+        with span("backend.upload"):
+            n = len(seqs)
+            old = self._slots
+            for i, s in enumerate(seqs):
+                if i < len(old) and old[i][0] is s and old[i][1] is s.pages:
+                    continue
+                self._m_bt[i, :] = self._scratch
+                self._m_bt[i, :len(s.pages)] = s.pages
+                self._m_lens[i] = s.pos - 1    # last token not yet in cache
+                self._m_toks[i] = s.tokens[-1]
+                sp = s.req.sampling
+                self._m_temps[i] = sp.temperature
+                self._m_topks[i] = sp.top_k
+                self._m_seeds[i] = sp.seed
+            for i in range(n, len(old)):           # rows that shrank away
+                self._m_bt[i, :] = self._scratch
+                self._m_lens[i] = 0
+                self._m_toks[i] = 0
+                self._m_temps[i] = 0.0
+                self._m_topks[i] = 0
+                self._m_seeds[i] = 0
+            self._slots = [(s, s.pages) for s in seqs]
+            npg_need = max(len(s.pages) for s in seqs)
+            if self.bucket_shapes:
+                self._nb = bucket(n, self._bcap)
+                self._npgb = bucket(npg_need, self._npg_cap)
+            else:
+                self._nb, self._npgb = n, npg_need
+            self._dstate = self._put({
+                "bt": self._m_bt,
+                "lens": self._m_lens,
+                "toks": self._m_toks,
+                "temps": self._m_temps,
+                "top_ks": self._m_topks,
+                "seeds": self._m_seeds,
+            })
 
     # ------------------------------------------------------------ shapes
     # (one implementation for every caller: repro.serving.bucketing)

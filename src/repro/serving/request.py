@@ -95,7 +95,11 @@ class GenRequest:
     # ("cancelled" | "deadline"); the next host to see the request resolves
     # it exactly once
     cancelled: Optional[str] = None
-    # filled by the engine:
+    # filled by the engine, on the same clock as `arrival_s`:
+    dispatched_s: Optional[float] = None   # reached Engine.submit
+    admitted_s: Optional[float] = None     # just before the engine step
+                                           # that first admitted it (a
+                                           # preemption resume keeps it)
     cached_tokens: int = 0
     first_token_s: Optional[float] = None
     finished_s: Optional[float] = None
@@ -122,6 +126,7 @@ class GenRequest:
         clone = dataclasses.replace(
             self, rid=(next_rid() if fresh_rid else self.rid),
             deadline_s=None, cancelled=None, arrival_s=None,
+            dispatched_s=None, admitted_s=None,
             cached_tokens=0, first_token_s=None, finished_s=None,
             on_admit=None, on_token=None, on_done=None)
         # predetermined completion (cost-backend replay) is content, not

@@ -26,6 +26,7 @@ from repro.routing.failover import FailoverTracker
 from repro.serving.engine import Engine
 from repro.serving.request import (GenRequest, GenResult,
                                    cancel_finish_reason)
+from repro.serving.spans import span
 
 
 class _TickTransport:
@@ -505,6 +506,18 @@ class InProcessRouter:
         """One global tick: deliver in-flight WAN messages, fire due
         heartbeats (which dispatch), run failover, then step every engine
         one continuous-batching iteration."""
+        with span("router.tick"):
+            with span("router.route"):
+                self._route()
+            done = 0
+            for lb in self.lbs.values():
+                for e in lb.engines.values():
+                    done += e.step()
+            self.tick += 1
+            return done
+
+    def _route(self) -> None:
+        """What a tick does before its engines step."""
         self._run_mail()
         self._sweep_deadlines()
         if self.tick % self.probe_every == 0:
@@ -521,12 +534,6 @@ class InProcessRouter:
             if lb.alive:
                 lb.core.maybe_steal()
         self._controller_check()
-        done = 0
-        for lb in self.lbs.values():
-            for e in lb.engines.values():
-                done += e.step()
-        self.tick += 1
-        return done
 
     def run_until_idle(self, max_steps: int = 100_000) -> None:
         for _ in range(max_steps):

@@ -119,6 +119,7 @@ def test_clone_for_dispatch_resets_lifecycle():
                session_key="sess", priority=2, slo_class="latency")
     req.arrival_s, req.cancelled, req.cached_tokens = 5.0, "cancelled", 3
     req.first_token_s = 6.0
+    req.dispatched_s, req.admitted_s = 5.5, 5.7
     req.on_done = done.append
     req.output_tokens = (11, 12)
     clone = req.clone_for_dispatch()
@@ -132,6 +133,7 @@ def test_clone_for_dispatch_resets_lifecycle():
     # cancel, no inherited callbacks double-firing the primary's handle
     assert clone.deadline_s is None and clone.cancelled is None
     assert clone.arrival_s is None and clone.first_token_s is None
+    assert clone.dispatched_s is None and clone.admitted_s is None
     assert clone.cached_tokens == 0
     assert clone.on_admit is None and clone.on_token is None \
         and clone.on_done is None

@@ -192,15 +192,25 @@ def test_steady_state_uploads_nothing(qwen_reduced, qwen_model_params):
         return orig(seqs)
 
     eng.backend._sync_slots = counting
+    uploads = eng.backend.state_uploads
     for _ in range(10):
         eng.step()
     assert syncs["n"] == 0                      # membership never changed
+    assert eng.backend.state_uploads == uploads
     eng.run_until_idle()
     assert eng.completions == 2
     # streaming delivered all 40 tokens, in order, while uploading nothing
     assert len(events) == 40
     for rid in set(r for r, _ in events):
         assert [i for r, i in events if r == rid] == list(range(20))
+    # a new member: one upload, at its first decode, and none after it
+    eng.backend._sync_slots = orig
+    eng.submit(_reqs(qwen_reduced.vocab, [(12, dict(max_new_tokens=5))])[0])
+    eng.step()                                  # prefill only
+    assert eng.backend.state_uploads == uploads
+    for _ in range(3):
+        eng.step()
+    assert eng.backend.state_uploads == uploads + 1
 
 
 # -------------------------------------------------------------- bucketing
