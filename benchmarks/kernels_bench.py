@@ -18,10 +18,6 @@ def _vmem_flash(bq, bk, hd):
     return (bq * hd + 2 * bk * hd) * 2 + (bq * 1 * 2 + bq * hd) * 4
 
 
-def _vmem_paged(page, H, K, hd):
-    return (H * hd + 2 * page * K * hd) * 2 + (2 * H + H * hd) * 4
-
-
 def _vmem_verify(page, H, Q, K, hd):
     # q tile folded to (K, G*Q, hd) + k/v page tiles + scratch (m, l, acc)
     return (H * Q * hd + 2 * page * K * hd) * 2 + (2 * H * Q + H * Q * hd) * 4
@@ -44,14 +40,22 @@ def run() -> list[dict]:
             "lane_aligned": bk % 128 == 0 and 128 % 128 == 0,
             "grid": f"(B,H,{S//min(bq,S)},{S//min(bk,S)})"})
 
-    # paged decode tiles
+    # paged decode: the pages per block and VMEM the kernel's own rule
+    # picks at qwen3-0.6b's and deepseek-llm-7b's widths, 16-token bf16
+    # pages, for a short and the longest decode bucket
+    from repro.kernels import paged_decode as pd
+    hd, page = 128, 16
+    for Hq, Kv in ((16, 8), (32, 32)):
+        for npg in (16, 256):
+            ppb = pd.pages_per_block(page, Kv, hd, 2, npg)
+            rows.append({
+                "kernel": "paged_decode",
+                "tile": f"K{Kv}.npg{npg}.ppb{ppb}",
+                "vmem_kb": round(
+                    pd.vmem_bytes(ppb, page, Hq, Kv, hd, 2) / 1024, 1),
+                "lane_aligned": hd % 128 == 0,
+                "grid": f"(B,{-(-npg // ppb)})"})
     B, H, K, hd, page, Ptot, npg = 8, 16, 8, 128, 16, 64, 16
-    for pg in (16, 32, 64, 128):
-        rows.append({
-            "kernel": "paged_decode", "tile": f"page{pg}",
-            "vmem_kb": round(_vmem_paged(pg, 32, 8, 128) / 1024, 1),
-            "lane_aligned": 128 % 128 == 0,
-            "grid": f"(B,{(npg*page)//pg})"})
 
     # paged verify (speculative decoding: Q = k_spec + 1 queries per seq)
     from repro.kernels.paged_verify import paged_verify

@@ -28,7 +28,7 @@ def paged_decode_ref(q, k_pages, v_pages, block_table, seq_lens) -> jax.Array:
     q: (B,H,hd); k_pages/v_pages: (P,page,K,hd); block_table: (B,npages)
     int32 — entries at or beyond a sequence's live page count
     ceil(seq_len / page) are NEVER dereferenced and may hold arbitrary
-    garbage (matching the ragged Pallas kernel's clamped index map);
+    garbage (the ragged Pallas kernel copies live pages only);
     seq_lens: (B,) valid token counts, >= 1. fp32 softmax."""
     B, H, hd = q.shape
     Ptot, page, K, _ = k_pages.shape

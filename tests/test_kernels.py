@@ -9,6 +9,7 @@ import pytest
 
 from repro.kernels import ref
 from repro.kernels.flash_attention import flash_attention
+from repro.kernels import paged_decode as pd
 from repro.kernels.paged_decode import paged_decode
 from repro.kernels.paged_verify import paged_verify
 from repro.kernels.ssd_scan import ssd_scan
@@ -91,9 +92,10 @@ def test_paged_decode_length_edges():
 
 
 def test_paged_decode_ignores_garbage_pages():
-    """Entries past the ragged edge are never dereferenced: the clamped
-    index map means they may hold ARBITRARY int32 (even out-of-range page
-    ids) — results must not change, and nothing may crash."""
+    """Entries past the ragged edge are never dereferenced: the kernel
+    copies live pages only, so they may hold ARBITRARY int32 (even
+    out-of-range page ids) — results must not change, and nothing may
+    crash."""
     B, H, K, hd, page, Ptot, npg = 1, 4, 2, 32, 8, 16, 4
     q = _rand((B, H, hd), jnp.float32)
     kp = _rand((Ptot, page, K, hd), jnp.float32)
@@ -127,6 +129,66 @@ def test_paged_decode_ragged_sweep():
     out = paged_decode(q, kp, vp, bt, lens, interpret=True)
     want = ref.paged_decode_ref(q, kp, vp, bt, lens)
     np.testing.assert_allclose(np.asarray(out), np.asarray(want), atol=2e-5)
+
+
+def _poison_past_edge(bt, lens, page):
+    """Out-of-range int32 garbage in every table entry past a row's live
+    page count, which the kernel must never dereference."""
+    for i, n in enumerate(lens):
+        bt[i, (int(n) + page - 1) // page:] = RNG.integers(
+            -(2 ** 31), 2 ** 31 - 1)
+    return bt
+
+
+@pytest.mark.parametrize("H,K,hd,page,Ptot,npg,ppb,lens", [
+    # npg spans two blocks and is not a multiple of ppb
+    (8, 2, 32, 8, 24, 6, 4, [3, 20, 33, 47, 48]),
+    # live edge in the middle of a block, garbage in the rest of it
+    (4, 2, 32, 8, 24, 6, 4, [9, 26, 41]),
+    # seq_len 1 and full, over three blocks of two
+    (4, 2, 32, 8, 24, 6, 2, [1, 48]),
+    # MHA with 32 KV heads (deepseek's shape at a small head size)
+    (32, 32, 32, 8, 16, 6, 4, [1, 17, 40, 48]),
+    # 16-token pages, GQA 2:1 (qwen3's page and grouping)
+    (16, 8, 32, 16, 24, 6, 4, [5, 16, 17, 70, 96]),
+    # qwen3's whole page: 128 lanes, nothing padded
+    (16, 8, 128, 16, 24, 6, 4, [1, 33, 64, 96]),
+])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_paged_decode_multi_page_blocks(monkeypatch, H, K, hd, page, Ptot,
+                                        npg, ppb, lens, dtype):
+    """Blocks of several pages: copies, double buffering across blocks and
+    rows, and a last block cut at the live edge all match the oracle."""
+    itemsize = jnp.dtype(dtype).itemsize
+    monkeypatch.setattr(pd, "BLOCK_BYTES",
+                        ppb * pd.page_bytes(page, K, hd, itemsize))
+    assert pd.pages_per_block(page, K, hd, itemsize, npg) == ppb
+    B = len(lens)
+    q = _rand((B, H, hd), dtype)
+    kp = _rand((Ptot, page, K, hd), dtype)
+    vp = _rand((Ptot, page, K, hd), dtype)
+    bt = _poison_past_edge(RNG.integers(0, Ptot, size=(B, npg)).astype(
+        np.int32), lens, page)
+    bt, lens = jnp.asarray(bt), jnp.asarray(lens, jnp.int32)
+    out = paged_decode(q, kp, vp, bt, lens, interpret=True)
+    want = ref.paged_decode_ref(q, kp, vp, bt, lens)
+    np.testing.assert_allclose(np.asarray(out, np.float32),
+                               np.asarray(want, np.float32),
+                               atol=TOL[dtype], rtol=TOL[dtype])
+
+
+@pytest.mark.parametrize("H,K,ppb", [
+    (16, 8, 32),       # qwen3-0.6b: 32 KiB pages
+    (32, 32, 8),       # deepseek-llm-7b: 128 KiB pages
+])
+def test_pages_per_block_rule(H, K, ppb):
+    """The rule gives more pages to narrower pages, caps at the table's
+    width, and keeps the kernel's buffers inside the scoped VMEM default."""
+    page, hd = 16, 128
+    assert pd.pages_per_block(page, K, hd, 2, 256) == ppb
+    assert pd.pages_per_block(page, K, hd, 2, 1) == 1
+    assert pd.pages_per_block(page, K, hd, 2, ppb - 1) == ppb - 1
+    assert pd.vmem_bytes(ppb, page, H, K, hd, 2) < 8 * 2 ** 20
 
 
 # ---------------------------------------------------------------- verify

@@ -100,6 +100,40 @@ def test_serving_kernel_compiles_for_v5e(one_chip, name):
     assert "tpu_custom_call" in text
 
 
+# (H, K, pool pages) of qwen3-0.6b and deepseek-llm-7b, as the cells run them
+WIDTHS = {"qwen3": (16, 8, 4096), "deepseek": (32, 32, 2048)}
+
+
+@pytest.mark.parametrize("nb,npgb", [(1, 1), (8, 32), (32, 256)])
+@pytest.mark.parametrize("width", sorted(WIDTHS))
+def test_paged_decode_compiles_at_decode_buckets(one_chip, width, nb, npgb):
+    """The kernel at both cells' widths and the decode buckets' extremes:
+    one page of one row, and 32 rows of 256 pages."""
+    h, k, pages = WIDTHS[width]
+    s = lambda shape, dt: _spec(shape, dt, one_chip)           # noqa: E731
+    pool = s((pages, PAGE, k, HD), BF16)
+    text = jax.jit(paged_decode).lower(
+        s((nb, h, HD), BF16), pool, pool, s((nb, npgb), I32),
+        s((nb,), I32)).compile().as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+
+
+@pytest.mark.parametrize("h,k,hd", [
+    (16, 8, 64),       # granite-moe: half a lane tile
+    (32, 32, 112),     # zamba2: no multiple of 128
+    (8, 1, 128),       # one bf16 KV head: half a packed sublane
+])
+def test_paged_decode_compiles_for_untiled_pages(one_chip, h, k, hd):
+    """Pages that do not fill whole (sublane, lane) tiles are padded to
+    them before the kernel's copies, which Mosaic refuses otherwise."""
+    s = lambda shape, dt: _spec(shape, dt, one_chip)           # noqa: E731
+    pool = s((512, PAGE, k, hd), BF16)
+    text = jax.jit(paged_decode).lower(
+        s((B, h, hd), BF16), pool, pool, s((B, NPG), I32),
+        s((B,), I32)).compile().as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+
+
 def _two_layer_qwen():
     """qwen3-0.6b at published widths, depth cut to 2 layers."""
     return dataclasses.replace(get_config("qwen3-0.6b"), n_layers=2)
@@ -126,7 +160,9 @@ def test_decode_and_prefill_steps_compile_for_v5e(one_chip, on_tpu):
              "top_ks": s((bcap,), I32), "seeds": s((bcap,), I32)}
     decode = mr.decode_step.lower(params, state, kp, vp, key, cfg=cfg,
                                   page_size=PAGE, nb=8, npgb=16).compile()
-    assert "tpu_custom_call" in decode.as_text()
+    # one kernel a layer: the paged-attention call inside the layer scan
+    assert decode.as_text().count(
+        'custom_call_target="tpu_custom_call"') == 1
 
     S, NSEG, CP = 512, 8, 64
     tok_rows = [s((S,), I32)] * 5
