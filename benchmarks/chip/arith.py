@@ -1,17 +1,17 @@
 """Operations and bytes the algorithm needs, from shapes alone, and the
 chip's peaks. Kept with the benchmark so that every change is measured
-with the same arithmetic.
+with the same arithmetic. What depends on an architecture's layout (which
+layers run which kernel over which contexts, the operations of a token)
+is in its module under arch/, which calls these.
 
 Conventions: a (m, k) x (k, n) matrix product costs 2mkn operations; one
 decoded token attending to `ctx` cached tokens costs 4 * ctx * heads *
-head_dim (scores and the weighted sum).
+head size (scores and the weighted sum).
 """
 from __future__ import annotations
 
 import json
 from pathlib import Path
-
-from benchmarks.chip.cell import head_dim
 
 PEAKS = Path(__file__).resolve().parent / "peaks.json"
 
@@ -25,37 +25,14 @@ def peaks(device_kind: str, path: Path = PEAKS) -> dict:
     return table[device_kind]
 
 
-def _dims(config: dict) -> tuple:
-    d, f = config["hidden_size"], config["intermediate_size"]
-    H, K = config["num_attention_heads"], config["num_key_value_heads"]
-    return d, f, H, K, head_dim(config), config["num_hidden_layers"], \
-        config["vocab_size"]
-
-
-def dense_token_flops(config: dict) -> float:
-    """Matrix-product operations of one token through every layer and
-    the output head (the dense-layer arithmetic of repro.analysis.flops:
-    q, k, v and output projections, gated MLP)."""
-    d, f, H, K, hd, L, V = _dims(config)
-    proj = 2.0 * d * (2 * H * hd + 2 * K * hd)
-    mlp = 3 * 2.0 * d * f
-    return L * (proj + mlp) + 2.0 * d * V
-
-
-def decode_row_flops(config: dict, ctx: int) -> float:
-    """One decoded token with `ctx` tokens in its cache (itself included)."""
-    d, f, H, K, hd, L, V = _dims(config)
-    return dense_token_flops(config) + L * 4.0 * ctx * H * hd
-
-
-def paged_decode_cost(config: dict, ctxs: list, kv_bytes: int = 2) -> tuple:
+def paged_decode_cost(ctxs: list, heads: int, kv_heads: int, hd: int,
+                      kv_bytes: int = 2) -> tuple:
     """(operations, bytes) of one paged-attention kernel call (one layer)
-    over the live rows: K and V of every live token, plus q in and the
-    output out."""
-    d, f, H, K, hd, L, V = _dims(config)
+    over the live rows with these contexts: K and V of every live token,
+    plus q in and the output out."""
     toks = sum(ctxs)
-    flops = 4.0 * toks * H * hd
-    nbytes = (2 * toks * K * hd + 2 * len(ctxs) * H * hd) * kv_bytes
+    flops = 4.0 * toks * heads * hd
+    nbytes = (2 * toks * kv_heads * hd + 2 * len(ctxs) * heads * hd) * kv_bytes
     return flops, nbytes
 
 

@@ -2,9 +2,13 @@
 
 `BENCHMARK.json` names each cell's configuration and traffic mix. The rest
 lives in files of their own under this directory, so a later change adds a
-cell, a configuration, a deployment or a mix by adding files:
+cell, a configuration, an architecture, a deployment or a mix by adding
+files:
 
-  configs/<config>.json       published sizes, dtype, engine settings
+  configs/<config>.json       published sizes, dtype, engine settings,
+                              and the architecture module ("arch")
+  arch/<arch>.py              what depends on the architecture's keys
+  <reference>.py              its plain reference ("reference")
   traffic/<traffic>.json      the mix's parameters (one general generator)
   cells/<workload>.json       the cell's own: deployment name and fixed rate
   deployments/<name>.json     regions, replicas per region, routing
@@ -14,6 +18,8 @@ from __future__ import annotations
 import dataclasses
 import json
 from pathlib import Path
+
+from benchmarks.chip import arch
 
 HERE = Path(__file__).resolve().parent
 ROOT = HERE.parents[1]
@@ -52,6 +58,7 @@ def load_cell(name: str, bench: dict | None = None) -> Cell:
         raise KeyError(f"no workload {name!r} in BENCHMARK.json; "
                        f"known: {known}")
     config = load_json(HERE / "configs" / f"{entry['config']}.json")
+    arch.load(config)
     own = load_json(HERE / "cells" / f"{name}.json")
     traffic = dict(load_json(HERE / "traffic" / f"{entry['traffic']}.json"))
     traffic.update(own.get("traffic", {}))
@@ -64,32 +71,12 @@ def load_cell(name: str, bench: dict | None = None) -> Cell:
 
 
 def model_config(config: dict):
-    """The program's ModelConfig for a configuration file (published key
-    names, as in the model's own config.json)."""
-    from repro.configs.base import ModelConfig
-    return ModelConfig(
-        name=config["name"], family="dense",
-        n_layers=config["num_hidden_layers"],
-        d_model=config["hidden_size"],
-        n_heads=config["num_attention_heads"],
-        n_kv_heads=config["num_key_value_heads"],
-        head_dim=config.get("head_dim", 0),
-        d_ff=config["intermediate_size"],
-        vocab=config["vocab_size"],
-        qk_norm=bool(config.get("qk_norm", False)),
-        gated_mlp=config.get("hidden_act", "silu") == "silu",
-        rope_theta=float(config["rope_theta"]),
-        norm_eps=float(config["rms_norm_eps"]),
-        tie_embeddings=bool(config["tie_word_embeddings"]),
-        max_seq_len=config["engine"]["max_seq_len"],
-        source=config["source"])
+    """The program's ModelConfig, from the configuration's own
+    architecture module."""
+    return arch.load(config).model_config(config)
 
 
 def engine_config(config: dict):
     from repro.serving import EngineConfig
     return EngineConfig(**config["engine"])
 
-
-def head_dim(config: dict) -> int:
-    return config.get("head_dim") or (config["hidden_size"]
-                                      // config["num_attention_heads"])

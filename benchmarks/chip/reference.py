@@ -17,6 +17,10 @@ Weights come in the served dtype and are widened to float32 one layer at a
 time inside the layer loop, so the reference fits beside them on the chip.
 Every product runs at `precision="highest"`.
 
+Another architecture's reference module brings its own layer function and
+shares the rest: `attend` (the attention above) and `gaps` (the pass, the
+comparison and the control).
+
 The control (`quant=True`) is the same pass with every matrix product's
 inputs rounded to fp8 (e4m3: 3 mantissa bits, largest 448), scaled
 weights per output channel and activations per token: the step below the
@@ -68,13 +72,11 @@ def _rope(x, pos, theta):
     return jnp.concatenate([x1 * c - x2 * s, x1 * s + x2 * c], -1)
 
 
-def _layer(h, lp, *, arch, quant):
-    eps, theta, qk_norm = arch
-    f32 = jax.tree.map(lambda a: a.astype(jnp.float32), lp)
-    T = h.shape[0]
+def attend(x, a, *, eps, theta, qk_norm, quant):
+    """Causal self-attention of the normed input `x` (T, d) over the whole
+    sequence, through the output projection; `a` holds float32 weights."""
+    T = x.shape[0]
     pos = jnp.arange(T)
-    x = _rms(h, f32["ln1"], eps)
-    a = f32["attn"]
     q = _mm(x, a["wq"], quant, 0)                      # (T, H, hd)
     k = _mm(x, a["wk"], quant, 0)                      # (T, K, hd)
     v = _mm(x, a["wv"], quant, 0)
@@ -95,7 +97,15 @@ def _layer(h, lp, *, arch, quant):
         outs.append(o.reshape(-1, H, hd))
     o = jnp.concatenate(outs, 0)
     wo = a["wo"].reshape(H * hd, -1)
-    h = h + _mm(o.reshape(T, H * hd), wo, quant, 0)
+    return _mm(o.reshape(T, H * hd), wo, quant, 0)
+
+
+def _layer(h, lp, *, arch, quant):
+    eps, theta, qk_norm = arch
+    f32 = jax.tree.map(lambda a: a.astype(jnp.float32), lp)
+    x = _rms(h, f32["ln1"], eps)
+    h = h + attend(x, f32["attn"], eps=eps, theta=theta, qk_norm=qk_norm,
+                   quant=quant)
     x = _rms(h, f32["ln2"], eps)
     m = f32["mlp"]
     up = _mm(x, m["w_up"], quant, 0)
@@ -104,12 +114,12 @@ def _layer(h, lp, *, arch, quant):
     return h, None
 
 
-@functools.partial(jax.jit, static_argnames=("arch", "quant"))
-def _forward(params, tokens, rows, *, arch, quant):
-    """Logits at `rows` of the causal pass over `tokens`."""
-    eps = arch[0]
+@functools.partial(jax.jit, static_argnames=("layer", "eps", "arch", "quant"))
+def _forward(params, tokens, rows, *, layer, eps, arch, quant):
+    """Logits at `rows` of the causal pass over `tokens`; `layer(h, lp,
+    arch=arch, quant=quant)` is one layer's step of the scan."""
     h = params["embedding"][tokens].astype(jnp.float32)
-    h, _ = jax.lax.scan(functools.partial(_layer, arch=arch, quant=quant),
+    h, _ = jax.lax.scan(functools.partial(layer, arch=arch, quant=quant),
                         h, params["layers"])
     x = _rms(h[rows], params["final_norm"].astype(jnp.float32), eps)
     if "lm_head" in params:
@@ -138,9 +148,10 @@ def _padded(tokens: list, step: int = 512) -> np.ndarray:
     return out
 
 
-def served_gaps(params, config: dict, prompt: tuple, served: list,
-                control: bool = False) -> dict:
-    """Gaps of one served request, by the float32 reference.
+def gaps(params, prompt: tuple, served: list, control: bool, *, layer,
+         eps: float, arch: tuple) -> dict:
+    """Gaps of one served request, by the float32 reference whose layer is
+    `layer` (with its static `arch` tuple).
 
     Position i of the output is predicted from the prompt and served[:i];
     the program's gap there is the reference's best logit minus its logit
@@ -149,11 +160,20 @@ def served_gaps(params, config: dict, prompt: tuple, served: list,
     seq = list(prompt) + list(served[:-1])
     toks = jnp.asarray(_padded(seq))
     rows = jnp.arange(len(prompt) - 1, len(prompt) - 1 + len(served))
-    arch = _arch(config)
-    ref = _forward(params, toks, rows, arch=arch, quant=False)
+    run = functools.partial(_forward, params, toks, rows, layer=layer,
+                            eps=eps, arch=arch)
+    ref = run(quant=False)
     out = {"program": np.asarray(_gap_of(ref, jnp.asarray(served,
                                                           jnp.int32)))}
     if control:
-        low = _forward(params, toks, rows, arch=arch, quant=True)
+        low = run(quant=True)
         out["control"] = np.asarray(_gap_of(ref, jnp.argmax(low, axis=1)))
     return out
+
+
+def served_gaps(params, config: dict, prompt: tuple, served: list,
+                control: bool = False) -> dict:
+    """Gaps of one served request of a dense configuration (see gaps)."""
+    arch = _arch(config)
+    return gaps(params, prompt, served, control, layer=_layer, eps=arch[0],
+                arch=arch)

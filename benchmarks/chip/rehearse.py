@@ -7,9 +7,11 @@ no chip attached, and print what each needs of the chip's memory.
 
 Compiles the largest decode bucket (max_batch rows over max_seq_len) and
 the largest packed-prefill round the traffic can reach (the harness's
-warm set), at the configuration's widths and pool, and prints
-`memory_analysis()` of each beside the weights and the pools. A compile
-that passes is not a chip run.
+warm set), at the configuration's widths and pool (the parameter leaves
+from its architecture module), and prints `memory_analysis()` of each
+beside the weights and the pools, and the Pallas kernels each program
+holds, under the names the trace reduction gives them. A compile that
+passes is not a chip run.
 """
 from __future__ import annotations
 
@@ -36,7 +38,7 @@ def main(argv=None) -> int:
     from jax.experimental import topologies
     from jax.sharding import SingleDeviceSharding
 
-    from benchmarks.chip import cell as cellmod, serve, weights
+    from benchmarks.chip import arch, cell as cellmod, serve, trace, weights
     from repro.kernels import ops
     from repro.serving import model_runner as mr
 
@@ -58,7 +60,8 @@ def main(argv=None) -> int:
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
 
     dt = jnp.dtype(config["dtype"])
-    params = {k: spec(v, dt) for k, v in weights.shapes(config).items()}
+    params = {k: spec(v, dt)
+              for k, v in arch.load(config).shapes(config).items()}
     params = weights._nest(params)
     kp, vp = (spec(s.shape, s.dtype) for s in
               mr.kv_pool_spec(cfg, ecfg.n_pages, ecfg.page_size, dt))
@@ -81,7 +84,9 @@ def main(argv=None) -> int:
         out[name] = {k: getattr(m, k) for k in
                      ("argument_size_in_bytes", "output_size_in_bytes",
                       "alias_size_in_bytes", "temp_size_in_bytes")}
-        out[name]["tpu_custom_call"] = "tpu_custom_call" in compiled.as_text()
+        out[name]["kernels"] = sorted(
+            {trace.kernel_name(op) for op in compiled.as_text().splitlines()}
+            - {None})
         print(json.dumps({name: out[name]}), flush=True)
 
     nb, npgb = serve.warm_shapes(ecfg, mix)["decode"][-1]

@@ -155,8 +155,7 @@ def reduce_trace(tdir: Path, n_chips: int, n_engines: int) -> dict:
             "plane": plane,
             "busy_s": trace.covered(merged, lo, hi) * 1e-9,
             "programs": trace.programs(events, plane),
-            "paged_decode": trace.kernel_time(
-                events, plane, "decode_step", PAGED_DECODE_OP),
+            "kernels": trace.kernels(events, plane),
         })
     # engine i steps on chip i % n_chips: its host time is the part of its
     # Engine.step spans in which its own chip ran nothing
@@ -180,10 +179,6 @@ def reduce_trace(tdir: Path, n_chips: int, n_engines: int) -> dict:
                 ["Client.poll", "InProcessRouter.step", "Engine.step:0"]),
         },
     }
-
-
-# The Pallas paged-attention kernel: the one custom call in decode_step.
-PAGED_DECODE_OP = r'custom_call_target="tpu_custom_call"'
 
 
 # -------------------------------------------------------------- correctness

@@ -60,8 +60,53 @@ def test_per_program_and_per_kernel_time():
     assert progs["decode_step"] == {"time_s": pytest.approx(90e-9),
                                     "calls": 2}
     assert progs["prefill_pack_step"]["calls"] == 1
-    t, n = trace.kernel_time(SYNTH, DEV, "decode_step", r"tpu_custom_call")
+    t, n = trace.kernels(SYNTH, DEV)["decode_step"]["closed_call"]
     assert n == 2 and t == pytest.approx(45e-9)
+    assert "prefill_pack_step" not in trace.kernels(SYNTH, DEV)
+
+
+@pytest.mark.parametrize("op, name", [
+    (KERNEL, "closed_call"),
+    ('%paged_decode.3.1 = bf16[8] custom-call(), '
+     'custom_call_target="tpu_custom_call"', "paged_decode"),
+    ('  ROOT %moe_gmm = bf16[8] custom-call(), '
+     'custom_call_target="tpu_custom_call"', "moe_gmm"),
+    ("%fusion.1 = bf16[8] fusion(%a)", None),
+    ('%sort.2 = s32[8] custom-call(), custom_call_target="TopK"', None),
+])
+def test_kernel_names(op, name):
+    assert trace.kernel_name(op) == name
+
+
+def test_a_second_kernel_in_the_decode_step_keeps_its_own_entry():
+    from benchmarks.chip.metrics import paged_decode_roofline
+    other = ('%moe_gmm.2 = bf16[8] custom-call(), '
+             'custom_call_target="tpu_custom_call"')
+    two = SYNTH + [_ev("XLA Ops", other, 146, 3),
+                   _ev("XLA Ops", other, 300, 8)]
+    one_k, two_k = trace.kernels(SYNTH, DEV), trace.kernels(two, DEV)
+    assert two_k["decode_step"]["closed_call"] == \
+        one_k["decode_step"]["closed_call"]
+    assert two_k["decode_step"]["moe_gmm"] == [pytest.approx(11e-9), 2]
+    cfg = {"arch": "dense", "hidden_size": 1024, "intermediate_size": 3072,
+           "num_hidden_layers": 1, "num_attention_heads": 16,
+           "num_key_value_heads": 8, "head_dim": 128, "vocab_size": 151936}
+
+    def rec(kernels):
+        return {"config": cfg, "peak": arith.peaks("TPU v5 lite"),
+                "decode": [(0, 1, [100, 300]), (1, 2, [101])],
+                "trace": {"chips": [{"kernels": kernels}]}}
+    got = paged_decode_roofline.read(rec(two_k))
+    assert got is not None and got == paged_decode_roofline.read(rec(one_k))
+    # the roofline of one layer's call per step, over the kernel's 45 ns
+    least = sum(arith.roofline_s(*arith.paged_decode_cost(c, 16, 8, 128),
+                                 arith.peaks("TPU v5 lite"))
+                for c in ([100, 300], [101]))
+    assert got == pytest.approx(100 * least / 45e-9)
+    # a kernel given its own name is read under it, whatever else runs
+    named = {"decode_step": {"paged_decode": [45e-9, 2],
+                             "closed_call": [9e-9, 7]}}
+    assert paged_decode_roofline.read(rec(named)) == pytest.approx(got)
 
 
 def test_host_spans_not_covered_by_the_device():
@@ -100,7 +145,7 @@ def test_recorded_v5e_trace():
     assert trace.covered(merged, lo, hi) == want["busy_ns"]
     progs = trace.programs(ev, DEV)
     assert progs["decode_step"]["calls"] == want["decode_calls"]
-    t, n = trace.kernel_time(ev, DEV, "decode_step", want["kernel"])
+    t, n = trace.kernels(ev, DEV)["decode_step"][want["kernel"]]
     assert n == want["kernel_calls"]
     assert t * 1e9 == pytest.approx(want["kernel_ns"])
 
@@ -133,16 +178,9 @@ def test_peaks_by_device_kind():
 
 
 def test_roofline_arithmetic():
-    cfg = {"hidden_size": 1024, "intermediate_size": 3072,
-           "num_hidden_layers": 28, "num_attention_heads": 16,
-           "num_key_value_heads": 8, "head_dim": 128, "vocab_size": 151936}
-    flops, nbytes = arith.paged_decode_cost(cfg, [100, 300])
+    # qwen3-0.6b's attention widths: 16 heads, 8 KV heads of 128
+    flops, nbytes = arith.paged_decode_cost([100, 300], 16, 8, 128)
     assert flops == 4.0 * 400 * 16 * 128
     assert nbytes == (2 * 400 * 8 * 128 + 2 * 2 * 16 * 128) * 2
     peak = arith.peaks("TPU v5 lite")
     assert arith.roofline_s(flops, nbytes, peak) == nbytes / 819e9
-    # qwen3-0.6b: 0.44 B matrix parameters in the layers plus the head
-    per_tok = arith.dense_token_flops(cfg)
-    assert per_tok == pytest.approx(
-        28 * (2 * 1024 * (2 * 2048 + 2 * 1024) + 6 * 1024 * 3072)
-        + 2 * 1024 * 151936)

@@ -3,9 +3,8 @@ per jitted program, per kernel, and host spans on the same clock.
 
 `load(path)` flattens the `.xplane.pb` that `jax.profiler` writes into
 plain event dicts (plane, line, name, start and duration in ns).
-Everything after that works
-on such lists, so it is tested on a small recorded trace kept beside the
-tests.
+Everything after that works on such lists, so it is tested on a small
+recorded trace kept beside the tests.
 
 Device planes are named `/device:TPU:<n>`. On each, the `XLA Ops` line
 holds one event per operation that ran; the union of those intervals is
@@ -13,9 +12,11 @@ the device's busy time. An op's name is its HLO instruction text;
 a loop's op spans its body's ops. The `XLA Modules` line holds one event
 per execution of a compiled program (`jit_decode_step(...)`); an op
 belongs to the program whose execution it falls in. A Pallas kernel is
-an op with `custom_call_target="tpu_custom_call"`. Host spans that
-the benchmark opens with `jax.profiler.TraceAnnotation` sit on the host
-plane's threads, on the same clock.
+an op with `custom_call_target="tpu_custom_call"`, named by its HLO
+instruction name (`kernel_name`): on a TPU v5 lite the event carries no
+other field that tells kernels apart. Host spans that the benchmark opens
+with `jax.profiler.TraceAnnotation` sit on the host plane's threads, on
+the same clock.
 """
 from __future__ import annotations
 
@@ -94,35 +95,46 @@ def programs(events: list, plane: str) -> dict:
     return out
 
 
-def module_intervals(events: list, plane: str, program: str) -> list:
-    return [(e["start_ns"], e["start_ns"] + e["dur_ns"]) for e in events
-            if e["plane"] == plane and e["line"] == MODULES_LINE
-            and program_name(e["name"]) == program]
+def _program_at(events: list, plane: str):
+    """A function from a time on `plane` to the program whose execution
+    covers it ("?" where none does)."""
+    mods = sorted((e["start_ns"], e["start_ns"] + e["dur_ns"],
+                   program_name(e["name"])) for e in events
+                  if e["plane"] == plane and e["line"] == MODULES_LINE)
+    starts = [m[0] for m in mods]
+
+    def at(t: int) -> str:
+        i = bisect.bisect_right(starts, t) - 1
+        return mods[i][2] if i >= 0 and t < mods[i][1] else "?"
+    return at
 
 
-def ops_within(events: list, plane: str, intervals: list,
-               pred=lambda e: True) -> list:
-    """Op events on `plane` that start inside any of `intervals` and
-    satisfy `pred`."""
-    merged = union(intervals)
-    starts = [s for s, _ in merged]
-    out = []
+def kernel_name(op: str) -> str | None:
+    """The name of the Pallas kernel that an op runs, None for any other
+    op: the op's HLO instruction name without `%` and its `.N` suffixes.
+    `pallas_call(..., name=...)` sets it; a call given no name takes the
+    name of the scope around it."""
+    if 'custom_call_target="tpu_custom_call"' not in op:
+        return None
+    head = op.split(" = ", 1)[0].split()[-1].lstrip("%")   # past any ROOT
+    return re.sub(r"(\.\d+)+$", "", head)
+
+
+def kernels(events: list, plane: str) -> dict:
+    """{program: {kernel: [seconds, calls]}} of the Pallas kernels whose
+    ops started inside that program's executions on `plane`."""
+    at = _program_at(events, plane)
+    out: dict = {}
     for e in events:
-        if e["plane"] != plane or e["line"] != OPS_LINE or not pred(e):
+        if e["plane"] != plane or e["line"] != OPS_LINE:
             continue
-        i = bisect.bisect_right(starts, e["start_ns"]) - 1
-        if i >= 0 and e["start_ns"] < merged[i][1]:
-            out.append(e)
+        name = kernel_name(e["name"])
+        if name is not None:
+            k = out.setdefault(at(e["start_ns"]), {}).setdefault(
+                name, [0.0, 0])
+            k[0] += e["dur_ns"] * 1e-9
+            k[1] += 1
     return out
-
-
-def kernel_time(events: list, plane: str, program: str, kernel) -> tuple:
-    """(seconds, calls) of the ops inside `program`'s executions whose
-    name matches the `kernel` regular expression."""
-    pat = re.compile(kernel)
-    evs = ops_within(events, plane, module_intervals(events, plane, program),
-                     lambda e: bool(pat.search(e["name"])))
-    return sum(e["dur_ns"] for e in evs) * 1e-9, len(evs)
 
 
 def spans(events: list, name: str) -> list:
@@ -162,18 +174,13 @@ def leaves(events: list, plane: str) -> list:
 def top_ops(events: list, plane: str, lo: int, hi: int, n: int = 10) -> list:
     """The n leaf ops, by program and label, that took the most device
     time in [lo, hi)."""
-    mods = sorted((e["start_ns"], e["start_ns"] + e["dur_ns"],
-                   program_name(e["name"])) for e in events
-                  if e["plane"] == plane and e["line"] == MODULES_LINE)
-    starts = [m[0] for m in mods]
+    at = _program_at(events, plane)
     tot: dict = {}
     for e in leaves(events, plane):
         t = covered([[e["start_ns"], e["start_ns"] + e["dur_ns"]]], lo, hi)
         if not t:
             continue
-        i = bisect.bisect_right(starts, e["start_ns"]) - 1
-        prog = mods[i][2] if i >= 0 and e["start_ns"] < mods[i][1] else "?"
-        key = f"{prog}/{op_label(e['name'])}"
+        key = f"{at(e['start_ns'])}/{op_label(e['name'])}"
         tot[key] = tot.get(key, 0) + t
     return [[k, v * 1e-9] for k, v in
             sorted(tot.items(), key=lambda kv: -kv[1])[:n]]
